@@ -3,11 +3,10 @@
 Times the stream-sharded fleet pipeline of :mod:`repro.sharding.fleet`
 at growing device counts (1 / 2 / 4 / 8 host-platform devices — the CI
 CPU runner fakes them with ``--xla_force_host_platform_device_count``,
-set below *before* jax imports), the end-to-end device wire path
-(:func:`repro.sharding.fleet.fleet_wire`), and both wire packers — the
-host :class:`repro.core.protocol_engine.ProtocolEmitter` and its device
-twin :class:`repro.core.wire_device.DeviceProtocolEmitter` — on their
-dense-event worst case (every point a singleton).  Results land in the
+set below *before* jax imports), the end-to-end metrics path
+(:func:`repro.sharding.fleet.fleet_point_metrics`), and the wire packer
+:class:`repro.core.protocol_engine.ProtocolEmitter` on its dense-event
+worst case (every point a singleton).  Results land in the
 top-level ``BENCH_fleet.json`` so the scaling curve is tracked across
 PRs like the other three benches.
 
@@ -63,7 +62,7 @@ def fleet_bench():
         "config": {"streams": S, "t_len": T, "eps": EPS, "method": METHOD,
                    "protocol": PROTOCOL, "iters": ITERS, "smoke": SMOKE,
                    "backend": jax.default_backend(), "devices": n_dev},
-        "scaling": {}, "packer": {}, "packer_device": {},
+        "scaling": {}, "packer": {},
     }
     rows = []
 
@@ -88,19 +87,11 @@ def fleet_bench():
         rows.append((f"fleet/devices={d}", sec * 1e6,
                      f"{points / sec / 1e6:.1f}Mpts/s "
                      f"x{base / sec:.2f}"))
-    # End-to-end ingest: segmentation + device-resident wire packing
-    # (fleet_wire), the path a fleet push actually takes.  The metrics
-    # pipeline (fleet_point_metrics, float64 host finish included) is
-    # kept as its own row for continuity with earlier reports.
-    wire_mesh = fleet.fleet_mesh(counts[-1])
-    e2e = _time(lambda: fleet.fleet_wire(y, EPS, METHOD, PROTOCOL,
-                                         mesh=wire_mesh).fleet_nbytes)
-    report["scaling"]["end_to_end_max_devices"] = {
-        "seconds": e2e, "points_per_s": points / e2e}
-    rows.append((f"fleet/e2e@{counts[-1]}dev", e2e * 1e6,
-                 f"{points / e2e / 1e6:.1f}Mpts/s"))
+    # End-to-end metrics pipeline (fleet_point_metrics, float64 host
+    # finish included) on the widest mesh.
+    wide_mesh = fleet.fleet_mesh(counts[-1])
     e2e_m = _time(lambda: fleet.fleet_point_metrics(
-        y, EPS, METHOD, PROTOCOL, mesh=wire_mesh))
+        y, EPS, METHOD, PROTOCOL, mesh=wide_mesh))
     report["scaling"]["end_to_end_metrics"] = {
         "seconds": e2e_m, "points_per_s": points / e2e_m}
     rows.append((f"fleet/e2e-metrics@{counts[-1]}dev", e2e_m * 1e6,
@@ -135,33 +126,6 @@ def fleet_bench():
             "bytes_per_s": wire / sec, "wire_bytes": wire,
         }
         rows.append((f"fleet/packer/{proto}", sec * 1e6,
-                     f"{points / sec / 1e6:.1f}Mpts/s "
-                     f"{wire / sec / 1e6:.0f}MB/s"))
-
-    # Device packer twin: the same dense-event worst case through
-    # wire_device.DeviceProtocolEmitter — chunked device-resident pushes,
-    # bytes leave the device only as finished blobs.
-    from repro.core.wire_device import DeviceProtocolEmitter
-    for proto in ("singlestream", "singlestreamv", "implicit"):
-        def pack_dev(proto=proto):
-            em = DeviceProtocolEmitter(proto, S, max_run=127)
-            n = 0
-            for lo in range(0, T, 1024):
-                evc = jax_pla.SegmentOutput(ev.breaks[:, lo:lo + 1024],
-                                            ev.a[:, lo:lo + 1024],
-                                            ev.v[:, lo:lo + 1024])
-                for b in em.step_chunk(evc, dense64[:, lo:lo + 1024]):
-                    n += len(b)
-            for b in em.flush():
-                n += len(b)
-            return n
-        wire = pack_dev()
-        sec = _time(pack_dev)
-        report["packer_device"][proto] = {
-            "seconds": sec, "points_per_s": points / sec,
-            "bytes_per_s": wire / sec, "wire_bytes": wire,
-        }
-        rows.append((f"fleet/packer-device/{proto}", sec * 1e6,
                      f"{points / sec / 1e6:.1f}Mpts/s "
                      f"{wire / sec / 1e6:.0f}MB/s"))
 

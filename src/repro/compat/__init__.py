@@ -1,16 +1,12 @@
-"""Version-compatibility shims over the JAX API drift this repo spans.
+"""The JAX API surface that moves between releases, in one place.
 
-The codebase targets the newest JAX spellings (``pltpu.CompilerParams``,
-``jax.sharding.get_abstract_mesh`` / ``AxisType``, ``jax.set_mesh``,
-``jax.shard_map``); the supported floor is JAX 0.4.37, where those names
-are ``pltpu.TPUCompilerParams``, the thread-local mesh context, the
-``Mesh`` context manager, and ``jax.experimental.shard_map.shard_map``.
+The repo supports the one installed JAX (0.9).  Pallas-TPU launch
+settings live in :mod:`repro.compat.pallas`, mesh and shard_map
+spellings in :mod:`repro.compat.sharding`.
 
-Policy: **no module outside this package may reference a
-version-dependent attribute directly** — every call site goes through
-:mod:`repro.compat.pallas` or :mod:`repro.compat.sharding`, so a future
-JAX bump is a compat-only diff.  See ROADMAP.md ("Supported JAX
-versions") for the tested range.
+Policy: **no module outside this package may reference those attributes
+directly** (``tests/test_compat.py``), so a JAX upgrade that renames one
+is a change to this package only.
 """
 
 from . import pallas, sharding  # noqa: F401

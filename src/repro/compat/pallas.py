@@ -1,62 +1,42 @@
-"""Pallas-TPU compatibility: compiler params, VMEM scratch, interpret mode.
+"""Pallas-TPU launch settings: compiler params, VMEM scratch, interpret mode.
 
-JAX renamed ``pltpu.TPUCompilerParams`` to ``pltpu.CompilerParams`` (and
-moved some of its knobs) across the 0.4 -> 0.5/0.6 line.  Everything here
-resolves the installed spelling once at import time; kernels call
-:func:`tpu_compiler_params` / :func:`vmem` and never touch ``pltpu``
-attributes that exist only on one side of the rename.
+Kernels call :func:`tpu_compiler_params` / :func:`vmem` /
+:func:`interpret_mode` and never touch ``pltpu`` attributes directly, so
+a JAX upgrade that renames them is a change to this module only.
 """
 
 from __future__ import annotations
 
-import dataclasses
 from typing import Any, Sequence
 
 import jax
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _resolve_compiler_params_cls():
-    """Installed compiler-params class: new name first, then the old one."""
-    cls = getattr(pltpu, "CompilerParams", None)
-    if cls is None:
-        cls = getattr(pltpu, "TPUCompilerParams", None)
-    if cls is None:  # pragma: no cover - no known JAX ships neither
-        raise ImportError(
-            "jax.experimental.pallas.tpu provides neither CompilerParams "
-            "nor TPUCompilerParams; unsupported JAX version "
-            f"{jax.__version__}")
-    return cls
-
-
-COMPILER_PARAMS_CLS = _resolve_compiler_params_cls()
-
-
-def tpu_compiler_params(*, dimension_semantics: Sequence[str] | None = None,
-                        **kwargs: Any):
-    """Build TPU compiler params portably.
-
-    Unknown fields are dropped (not errors): a knob that one JAX version
-    lacks simply falls back to that version's default, which keeps kernel
-    call sites declarative.
-    """
-    cls = COMPILER_PARAMS_CLS
-    fields = {f.name for f in dataclasses.fields(cls)}
-    want = dict(kwargs)
-    if dimension_semantics is not None:
-        want["dimension_semantics"] = tuple(dimension_semantics)
-    return cls(**{k: v for k, v in want.items() if k in fields})
+def tpu_compiler_params(*, dimension_semantics: Sequence[str]):
+    """TPU compiler params for a kernel grid."""
+    return pltpu.CompilerParams(
+        dimension_semantics=tuple(dimension_semantics))
 
 
 def vmem(shape: Sequence[int], dtype) -> Any:
-    """VMEM scratch allocation (stable across versions, wrapped for policy)."""
+    """VMEM scratch allocation."""
     return pltpu.VMEM(tuple(shape), dtype)
 
 
 def interpret_mode() -> bool:
-    """Pallas ``interpret=True`` everywhere except a real TPU backend.
+    """Whether Pallas kernels run in ``interpret=True`` mode.
 
-    Interpret mode executes the kernel body with bit-accurate semantics at
-    Python speed, which is what keeps the whole suite runnable on CPU.
+    False on a TPU backend (compiled Mosaic kernels); True on the CPU
+    backend, where interpret mode executes the kernel body with the same
+    semantics at Python speed, which keeps the test suite runnable
+    without a chip.  Any other backend is an error: a kernel must never
+    fall back to interpret mode beside a device it should run on.
     """
-    return jax.default_backend() != "tpu"
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"Pallas kernels run on a TPU, or in interpret mode "
+                       f"on the CPU; the default backend is {backend!r}")

@@ -1,49 +1,28 @@
-"""Sharding compatibility: mesh context, axis types, shard_map.
+"""Sharding API in one place: mesh context, axis types, shard_map.
 
-JAX 0.4.x has no ``jax.sharding.AxisType`` / ``get_abstract_mesh`` /
-``jax.set_mesh`` / ``jax.shard_map``; the equivalents are the thread-local
-mesh context set by the ``Mesh`` context manager, and
-``jax.experimental.shard_map.shard_map`` (with ``auto=``/``check_rep=``
-instead of ``axis_names=``/``check_vma=``).  This module exposes one
-spelling for both worlds:
-
-- :data:`AxisType` — the installed enum, or a local stand-in on 0.4.x;
+- :data:`AxisType` — ``jax.sharding.AxisType``;
 - :func:`get_abstract_mesh` — a normalized :class:`MeshInfo` view of the
-  active mesh (``None`` when no mesh is active), with per-axis types
-  (legacy meshes report ``Manual`` for axes currently bound by an
-  enclosing ``shard_map``, ``Auto`` otherwise);
-- :func:`make_mesh` — ``jax.make_mesh`` passing ``axis_types`` only where
-  supported;
-- :func:`use_mesh` — ``jax.set_mesh`` / ``jax.sharding.use_mesh`` / the
-  legacy ``with mesh:`` context, whichever exists;
-- :func:`shard_map` — keyword-translated across the rename.
+  active mesh (``None`` when no mesh is active);
+- :func:`make_mesh` — ``jax.make_mesh`` with all-Auto axis types by
+  default;
+- :func:`use_mesh` — ``jax.set_mesh`` (``None`` -> no-op context);
+- :func:`shard_map` — ``jax.shard_map`` with partial-manual axes.
 """
 
 from __future__ import annotations
 
 import contextlib
 import dataclasses
-import enum
-import inspect
 from typing import Any, Dict, Iterable, Optional, Sequence, Tuple
 
 import jax
 
-_NATIVE_AXIS_TYPE = getattr(jax.sharding, "AxisType", None)
-
-if _NATIVE_AXIS_TYPE is not None:
-    AxisType = _NATIVE_AXIS_TYPE
-else:
-    class AxisType(enum.Enum):
-        """Stand-in for ``jax.sharding.AxisType`` on JAX 0.4.x."""
-        Auto = "auto"
-        Explicit = "explicit"
-        Manual = "manual"
+AxisType = jax.sharding.AxisType
 
 
 @dataclasses.dataclass(frozen=True)
 class MeshInfo:
-    """Version-independent view of the active (abstract) mesh.
+    """View of the active (abstract) mesh.
 
     ``shape`` maps axis name -> size in mesh order; ``axis_types`` aligns
     with ``shape.items()``.  Matches the parts of ``AbstractMesh`` that the
@@ -57,81 +36,18 @@ class MeshInfo:
         return tuple(self.shape)
 
 
-def _legacy_manual_axis_names() -> set:
-    """Axis names bound by an enclosing shard_map on JAX 0.4.x.
-
-    Those axes are in Manual mode: naming them in a
-    ``with_sharding_constraint`` spec is an error, so ``shard`` must be
-    able to identify and drop them.
-    """
-    try:
-        from jax._src import core as _core
-        return set(_core.get_axis_env().axis_sizes)
-    except Exception:
-        return set()
-
-
 def get_abstract_mesh() -> Optional[MeshInfo]:
-    """The active mesh as :class:`MeshInfo`, or ``None`` when there is none.
-
-    New JAX: ``jax.sharding.get_abstract_mesh()`` (the ``jax.set_mesh``
-    context).  JAX 0.4.x: the thread-local physical mesh set by the
-    ``Mesh`` context manager, with axis types inferred from the axis env.
-    """
-    native = getattr(jax.sharding, "get_abstract_mesh", None)
-    if native is not None:
-        m = native()
-        if m is None or not m.shape:
-            return None
-        return MeshInfo(dict(m.shape), tuple(m.axis_types))
-    from jax._src import mesh as _mesh_lib
-    m = _mesh_lib.thread_resources.env.physical_mesh
-    if m is None or m.empty:
+    """The active mesh as :class:`MeshInfo`, or ``None`` when there is none
+    (the ``jax.set_mesh`` context)."""
+    m = jax.sharding.get_abstract_mesh()
+    if m is None or not m.shape:
         return None
-    manual = _legacy_manual_axis_names()
-    if manual:
-        # Inside a (partial-manual) shard_map on 0.4.x: the SPMD
-        # partitioner cannot mix auto sharding constraints with manual
-        # subgroups (CHECK IsManualSubgroup) — report *every* axis Manual
-        # so constraint emitters degrade to unconstrained.  Newer JAX
-        # handles the mix and takes the native branch above instead.
-        types = tuple(AxisType.Manual for _ in m.axis_names)
-    else:
-        types = tuple(AxisType.Auto for _ in m.axis_names)
-    return MeshInfo(dict(m.shape), types)
+    return MeshInfo(dict(m.shape), tuple(m.axis_types))
 
 
 def axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis (inside shard_map / collectives).
-
-    ``jax.lax.axis_size`` is a newer addition; JAX 0.4.x exposes the same
-    static lookup through the axis env.
-    """
-    native = getattr(jax.lax, "axis_size", None)
-    if native is not None:
-        return native(axis_name)
-    from jax._src import core as _core
-    return _core.get_axis_env().axis_size(axis_name)
-
-
-def partial_auto_shard_map_supported() -> bool:
-    """Whether shard_map may be manual over a *subset* of the mesh axes.
-
-    On JAX 0.4.x the legacy ``auto=`` shard_map hits XLA SPMD partitioner
-    CHECKs (``IsManualSubgroup``) as soon as the body contains a
-    ``lax.scan`` or a gather-style collective (``all_gather``) — which
-    rules it out for any real model.  The ``jax.shard_map`` /
-    ``axis_names=`` rewrite fixed this, so the capability is keyed to the
-    ``axis_names`` kwarg itself — a transitional ``jax.shard_map`` that
-    still takes ``auto=`` shares the legacy lowering and must use the
-    fallbacks too.  When False, callers must either go fully manual over
-    every mesh axis (handling the extra axes with explicit collectives)
-    or keep collectives psum-shaped.
-    """
-    native = getattr(jax, "shard_map", None)
-    if native is None:
-        return False
-    return "axis_names" in inspect.signature(native).parameters
+    """Static size of a bound mesh axis (inside shard_map / collectives)."""
+    return jax.lax.axis_size(axis_name)
 
 
 def auto_axis_types(n: int) -> Tuple[Any, ...]:
@@ -142,82 +58,33 @@ def auto_axis_types(n: int) -> Tuple[Any, ...]:
 def make_mesh(axis_shapes: Sequence[int], axis_names: Sequence[str], *,
               axis_types: Optional[Sequence[Any]] = None,
               devices=None) -> jax.sharding.Mesh:
-    """``jax.make_mesh`` that forwards ``axis_types`` only where supported.
-
-    ``axis_types=None`` means all-Auto (passed explicitly on new JAX, the
-    implicit behavior of 0.4.x meshes).
-    """
-    kwargs: Dict[str, Any] = {}
-    if devices is not None:
-        kwargs["devices"] = devices
-    native = getattr(jax, "make_mesh", None)
-    if native is not None:
-        params = inspect.signature(native).parameters
-        if "axis_types" in params:
-            kwargs["axis_types"] = (tuple(axis_types) if axis_types is not None
-                                    else auto_axis_types(len(axis_names)))
-        return native(tuple(axis_shapes), tuple(axis_names), **kwargs)
-    # Pre-make_mesh JAX: build the device grid by hand.
-    import math
-    import numpy as np
-    devs = devices if devices is not None else \
-        jax.devices()[:math.prod(axis_shapes)]
-    grid = np.asarray(devs).reshape(tuple(axis_shapes))
-    return jax.sharding.Mesh(grid, tuple(axis_names))
+    """``jax.make_mesh``; ``axis_types=None`` means all-Auto."""
+    types = (tuple(axis_types) if axis_types is not None
+             else auto_axis_types(len(axis_names)))
+    return jax.make_mesh(tuple(axis_shapes), tuple(axis_names),
+                         axis_types=types, devices=devices)
 
 
 def use_mesh(mesh: Optional[jax.sharding.Mesh]):
-    """Context manager activating ``mesh`` (``None`` -> no-op context).
-
-    Resolves to ``jax.set_mesh`` (newest), ``jax.sharding.use_mesh``
-    (0.5.x), or the legacy ``with mesh:`` thread-local context (0.4.x) —
-    all of which make bare-``PartitionSpec`` sharding constraints resolve
-    against the mesh during tracing.
-    """
+    """Context manager activating ``mesh`` (``None`` -> no-op context), so
+    bare-``PartitionSpec`` sharding constraints resolve against it during
+    tracing."""
     if mesh is None:
         return contextlib.nullcontext()
-    native = getattr(jax, "set_mesh", None)
-    if native is None:
-        native = getattr(jax.sharding, "use_mesh", None)
-    if native is not None:
-        return native(mesh)
-    return mesh  # legacy Mesh is itself a context manager
+    return jax.set_mesh(mesh)
 
 
 def shard_map(f, *, mesh: jax.sharding.Mesh, in_specs, out_specs,
               axis_names: Optional[Iterable[str]] = None,
               check: bool = False):
-    """Portable shard_map with partial-manual axes.
+    """``jax.shard_map`` with partial-manual axes.
 
     ``axis_names`` lists the axes ``f`` is manual over (all axes when
-    ``None``); the rest stay automatically sharded.  On new JAX this is the
-    ``axis_names=`` kwarg; on 0.4.x it translates to ``auto=`` (the
-    complement).  ``check`` maps to ``check_vma``/``check_rep``.
+    ``None``); the rest stay automatically sharded.  ``check`` maps to
+    ``check_vma``.
     """
-    native = getattr(jax, "shard_map", None)
-    if native is not None:
-        params = inspect.signature(native).parameters
-        kwargs: Dict[str, Any] = {}
-        if axis_names is not None:
-            if "axis_names" in params:
-                kwargs["axis_names"] = set(axis_names)
-            elif "auto" in params:
-                # Transitional jax.shard_map with the legacy kwargs:
-                # translate to the complement rather than silently going
-                # fully manual over every axis.
-                auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-                if auto:
-                    kwargs["auto"] = auto
-        if "check_vma" in params:
-            kwargs["check_vma"] = check
-        elif "check_rep" in params:
-            kwargs["check_rep"] = check
-        return native(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                      **kwargs)
-    from jax.experimental.shard_map import shard_map as _legacy
-    kwargs = {"check_rep": check}
+    kwargs: Dict[str, Any] = {"check_vma": check}
     if axis_names is not None:
-        auto = frozenset(mesh.axis_names) - frozenset(axis_names)
-        if auto:
-            kwargs["auto"] = auto
-    return _legacy(f, mesh, in_specs, out_specs, **kwargs)
+        kwargs["axis_names"] = set(axis_names)
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, **kwargs)

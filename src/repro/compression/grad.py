@@ -175,18 +175,9 @@ def pod_compressed_mean(grads, ef, cfg: GradCompressionConfig,
         local_dec = local_rows.reshape(-1)[:n].reshape(g.shape)
         new_ef = g - local_dec          # residual stays local (EF)
         # Exchange records (+ escape rows) over the pod axis.
-        if compat_sharding.partial_auto_shard_map_supported():
-            gathered = jax.lax.all_gather((rec, raw_esc), axis_name)
-            decoded = jax.vmap(lambda re: dec_rows(*re))(gathered)
-            mean = decoded.mean(axis=0).reshape(-1)[:n].reshape(g.shape)
-        else:
-            # Decode is deterministic per pod, so pmean of the locally
-            # decoded rows equals the mean of all pods' decoded records;
-            # only decoded values (not records) cross the boundary here,
-            # which keeps the collective psum-shaped — the only kind the
-            # 0.4.x partitioner accepts under partial-manual shard_map.
-            mean = jax.lax.pmean(local_rows, axis_name) \
-                .reshape(-1)[:n].reshape(g.shape)
+        gathered = jax.lax.all_gather((rec, raw_esc), axis_name)
+        decoded = jax.vmap(lambda re: dec_rows(*re))(gathered)
+        mean = decoded.mean(axis=0).reshape(-1)[:n].reshape(g.shape)
         n_over = rec.overflow.sum()
         nbytes = jnp.float32(rec.seg_end.size + 2 * rec.a.size
                              + 2 * rec.v.size + rec.count.size) \
@@ -200,15 +191,8 @@ def pod_compressed_mean(grads, ef, cfg: GradCompressionConfig,
     new_ef = treedef.unflatten([o[1] for o in outs])
     wire_bytes = sum(o[2] for o in outs)
     raw_bytes = sum(jnp.full((), g.size * 4, jnp.float32) for g in flat)
-    # wire_bytes always reports the record protocol's traffic.  In the
-    # 0.4.x fallback the simulation collective actually moves decoded
-    # rows, so there the figure is *modeled* rather than measured —
-    # flagged so telemetry consumers can tell the two apart.
     stats = {"wire_bytes": wire_bytes, "raw_bytes": raw_bytes,
-             "n_pods": n_pods,
-             "wire_is_modeled": jnp.float32(
-                 0.0 if compat_sharding.partial_auto_shard_map_supported()
-                 else 1.0)}
+             "n_pods": n_pods}
     return mean, new_ef, stats
 
 

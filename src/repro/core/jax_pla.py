@@ -167,6 +167,7 @@ class SegmentOutput(NamedTuple):
 #   step(eps, max_run, window, carry, (t, y_t))
 #       -> (carry, (brk, a, v))                     (event for position t-1)
 #   flush(carry, t_last) -> (a_f, v_f)              (trailing-run line)
+#   flush(eps, max_run, window, carry, t_last)      (deferred methods)
 # ---------------------------------------------------------------------------
 
 
@@ -1015,7 +1016,7 @@ def _continuous_step(eps, max_run, window, state, inp):
     return new_state, (ev, pos_ev, a_ev, v_ev)
 
 
-def _continuous_flush(eps, window, carry, t_last):
+def _continuous_flush(eps, max_run, window, carry, t_last):
     """Fix the last knot; emit the pending segment + the trailing one.
 
     Deferred flushes return ``((ev1, pos1, a1, v1), (a2, v2))``: an
@@ -1033,7 +1034,11 @@ def _continuous_flush(eps, window, carry, t_last):
     v1 = jnp.where(ev1, Kv, 0.0)
     am = jnp.where(has2 == 1, 0.5 * (a_lo + a_hi), 0.0)
     dl = (jnp.asarray(t_last, jnp.int32) - g_pos).astype(dtype)
-    return (ev1, g_pos, a1, v1), (am, Kv + am * dl)
+    # The select keeps ``am * dl`` a separately rounded product: XLA:CPU
+    # contracts ``Kv + am * dl`` into an FMA in some fusions and not in
+    # others, so the chunked flush (its own jit) and the offline one
+    # (fused after the scan) differed by one ulp.
+    return (ev1, g_pos, a1, v1), (am, Kv + jnp.where(dl >= 0, am * dl, 0.0))
 
 
 # ---- MixedPLA: disjoint stage-1 runs + joint-merge stage-2 -----------------
@@ -1152,7 +1157,10 @@ def _mixed_step(eps, max_run, window, state, inp):
 
     jlo = jnp.maximum(plo, clo)
     jhi = jnp.minimum(phi, chi)
-    join = brk & (p_ex == 1) & (p_i1 - p_i0 >= 2) & (jlo <= jhi)
+    # A join hands the current run prev's last point: a run already at
+    # max_run stays disjoint so no segment exceeds max_run points.
+    join = brk & ~cap_hit & (p_ex == 1) & (p_i1 - p_i0 >= 2) \
+        & (jlo <= jhi)
     vK = 0.5 * (jlo + jhi)
 
     # Joint emission: prev shortened by one point, line through the knots.
@@ -1205,7 +1213,7 @@ def _mixed_step(eps, max_run, window, state, inp):
     return new_state, (ev, pos_ev, a_ev, v_ev)
 
 
-def _mixed_flush(eps, window, carry, t_last):
+def _mixed_flush(eps, max_run, window, carry, t_last):
     """Final join decision (prev vs the trailing run) + trailing segment."""
     (ybuf, run_start, rl, y0, prev_y, a_lo, v_lo, a_hi, v_hi,
      p_ex, p_i0, p_i1, p_lk, p_lk_pos, p_lk_val,
@@ -1243,7 +1251,8 @@ def _mixed_flush(eps, window, carry, t_last):
     chi = jnp.where(rl >= 2, jnp.maximum(cv1, cv2), _BIG)
     jlo = jnp.maximum(plo, clo)
     jhi = jnp.minimum(phi, chi)
-    join = (p_ex == 1) & (p_i1 - p_i0 >= 2) & (jlo <= jhi)
+    join = (rl < max_run) & (p_ex == 1) & (p_i1 - p_i0 >= 2) \
+        & (jlo <= jhi)
     vK = 0.5 * (jlo + jhi)
 
     m_jw = (abs_pos[None, :] >= p_i0[:, None]) \
@@ -1398,7 +1407,7 @@ def _segment_offline_deferred(method, y, eps, max_run, window):
     step = functools.partial(impl.step, eps, max_run, window)
     carry, (ev, pos, ea, ev_v) = jax.lax.scan(
         step, carry, (ts, y[:, 1:].T), unroll=_scan_unroll(method, T - 1))
-    flush_evs = impl.flush(eps, window, carry, T - 1)
+    flush_evs = impl.flush(eps, max_run, window, carry, T - 1)
     return assemble_deferred_events(S, T, dtype, ev.T, pos.T, ea.T, ev_v.T,
                                     flush_evs)
 
@@ -1642,7 +1651,7 @@ def _dstream_cont(method, max_run, window, carry, y_chunk, eps, t0):
 
 @functools.partial(jax.jit, static_argnames=("method", "max_run", "window"))
 def _dstream_flush(method, max_run, window, carry, eps, t_last):
-    return _METHOD_IMPLS[method].flush(eps, window, carry, t_last)
+    return _METHOD_IMPLS[method].flush(eps, max_run, window, carry, t_last)
 
 
 def release_deferred(pend, det, released: int, t_new: int, batches,

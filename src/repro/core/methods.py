@@ -429,7 +429,10 @@ def run_mixed(ts, ys, eps: float, max_run: Optional[int] = None) -> MethodOutput
         which then transfers to cur's coverage.
         """
         joined = False
-        if prev_run.i1 - prev_run.i0 >= 2:
+        # A join hands cur prev's last point: a run already at max_run
+        # stays disjoint so no segment exceeds max_run points.
+        room = max_run is None or cur_run.i1 - cur_run.i0 < max_run
+        if room and prev_run.i1 - prev_run.i0 >= 2:
             tau = float(ts[prev_run.i1 - 1])  # prev's last covered point
             plo, phi = prev_run.value_range_at(tau, ts, ys)
             clo, chi = cur_run.fitter.value_range_at(tau)

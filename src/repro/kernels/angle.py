@@ -64,7 +64,7 @@ def _angle_kernel(y_ref, cin, brk_ref, a_ref, v_ref, cout,
 
     def step(j, _):
         t_loc = ti * bt + j   # launch-local time
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))  # (1, BS)
+        yt = y_ref[pl.ds(j, 1), :]  # (1, BS)
 
         is_first = started[...] == 0
         ph, py = phase[...], p0y[...]
@@ -95,9 +95,9 @@ def _angle_kernel(y_ref, cin, brk_ref, a_ref, v_ref, cout,
         a_out = jnp.where(ph == 1, 0.5 * (s_lo + s_hi), 0.0)
         v_out = jnp.where(ph == 1, o_y + a_out * (o_d - 1.0), py)
 
-        pl.store(brk_ref, (pl.ds(j, 1), slice(None)), brk.astype(jnp.int8))
-        pl.store(a_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, a_out, 0.0))
-        pl.store(v_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, v_out, 0.0))
+        brk_ref[pl.ds(j, 1), :] = brk.astype(brk_ref.dtype)
+        a_ref[pl.ds(j, 1), :] = jnp.where(brk, a_out, 0.0)
+        v_ref[pl.ds(j, 1), :] = jnp.where(brk, v_out, 0.0)
 
         # Commit next state.
         go0 = (ph == 0) & ~brk & ~is_first     # origin just built

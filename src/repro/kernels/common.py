@@ -82,7 +82,11 @@ BLOCK_T = 128
 _BIG = jnp.float32(3.4e38)
 
 # Event outputs of every segmenter: break flag, slope, value-at-break.
-SEGMENT_EVENT_DTYPES = (jnp.int8, jnp.float32, jnp.float32)
+# The flag is 32-bit: kernels store one (1, block_s) row per time step at
+# a dynamic sublane index, which Mosaic lowers only for 32-bit tiles (an
+# int8 tile is (32, 128), so a one-row int8 store cannot be proven
+# aligned).
+SEGMENT_EVENT_DTYPES = (jnp.int32, jnp.float32, jnp.float32)
 
 
 def pad_streams(y: jax.Array, bs: int, bt: int):

@@ -45,7 +45,8 @@ from .common import BLOCK_S, BLOCK_T, launch_segmenter
 _BIG = 3.4e38
 
 _HEAD_ROWS = 13
-DEFERRED_EVENT_DTYPES = (jnp.int8, jnp.int32, jnp.float32, jnp.float32)
+# ev, pos, a, v — 32-bit flag for the same reason as SEGMENT_EVENT_DTYPES.
+DEFERRED_EVENT_DTYPES = (jnp.int32, jnp.int32, jnp.float32, jnp.float32)
 
 
 def cont_state_rows(window: int) -> int:
@@ -79,8 +80,8 @@ def continuous_flush_carry(carry: jax.Array, window: int, t_last: int):
     """Close the stream from a carry: the pending-segment event (if any)
     plus the trailing segment's line at launch-local ``t_last``."""
     eps = jnp.zeros((carry.shape[1],), jnp.float32)  # unused by this flush
-    return _continuous_flush(eps, window, cont_unpack_carry(carry, window),
-                             t_last)
+    return _continuous_flush(eps, None, window,
+                             cont_unpack_carry(carry, window), t_last)
 
 
 def _continuous_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
@@ -108,13 +109,14 @@ def _continuous_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         k_val[...] = cin[12:13, :]
         ring[...] = cin[_HEAD_ROWS:_HEAD_ROWS + W, :]
 
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, (W, 1), 0)
+    slot_iota = jax.lax.broadcasted_iota(
+        jnp.int32, (W, 1), 0).astype(jnp.float32)
 
     def step(j, _):
         t_loc = ti * bt + j
         live = t_loc < t_stop
         t = t_loc.astype(jnp.float32)
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))  # (1, BS)
+        yt = y_ref[pl.ds(j, 1), :]  # (1, BS)
         is_first = started[...] == 0
 
         gp, gl, gh = g_pos[...], glo[...], ghi[...]
@@ -135,13 +137,10 @@ def _continuous_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         dk = gp - kp
         dk_safe = jnp.where(dk > 0, dk, 1.0)
         evt = brk & (hk == 1)
-        pl.store(ev_ref, (pl.ds(j, 1), slice(None)), evt.astype(jnp.int8))
-        pl.store(pos_ref, (pl.ds(j, 1), slice(None)),
-                 jnp.where(evt, gp, 0.0).astype(jnp.int32))
-        pl.store(a_ref, (pl.ds(j, 1), slice(None)),
-                 jnp.where(evt, (Kv - kv) / dk_safe, 0.0))
-        pl.store(v_ref, (pl.ds(j, 1), slice(None)),
-                 jnp.where(evt, Kv, 0.0))
+        ev_ref[pl.ds(j, 1), :] = evt.astype(ev_ref.dtype)
+        pos_ref[pl.ds(j, 1), :] = jnp.where(evt, gp, 0.0).astype(jnp.int32)
+        a_ref[pl.ds(j, 1), :] = jnp.where(evt, (Kv - kv) / dk_safe, 0.0)
+        v_ref[pl.ds(j, 1), :] = jnp.where(evt, Kv, 0.0)
 
         # ---- run window (positions strictly after the gate) -------------
         tm1 = t - 1.0
@@ -205,8 +204,8 @@ def _continuous_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         k_val[...] = sel(0.0, Kv, kv, kv)
         started[...] = jnp.where(live, 1, started[...])
         row = pl.ds(jnp.mod(t_loc, W), 1)
-        cur_row = pl.load(ring, (row, slice(None)))
-        pl.store(ring, (row, slice(None)), jnp.where(live, yt, cur_row))
+        cur_row = ring[row, :]
+        ring[row, :] = jnp.where(live, yt, cur_row)
         return 0
 
     jax.lax.fori_loop(0, bt, step, 0)

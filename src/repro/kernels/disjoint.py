@@ -85,18 +85,19 @@ def _chain_pop(pos_ref, val_ref, ln, keep, px, py, slot_iota, upper: bool):
         return _gather(buf, idx, slot_iota)
 
     def flags(ln):
+        # int32, not bool: Mosaic cannot carry an i1 vector through a loop.
         can = keep & (ln >= 2)
         ox, oy = g(pos, jnp.maximum(ln - 2, 0)), g(val, jnp.maximum(ln - 2, 0))
         ax, ay = g(pos, jnp.maximum(ln - 1, 0)), g(val, jnp.maximum(ln - 1, 0))
         cr = (ax - ox) * (py - oy) - (ay - oy) * (px - ox)
-        return can & (cr >= 0 if upper else cr <= 0)
+        return (can & (cr >= 0 if upper else cr <= 0)).astype(jnp.int32)
 
     def body(st):
         ln, f = st
-        ln = jnp.where(f, ln - 1, ln)
+        ln = jnp.where(f != 0, ln - 1, ln)
         return ln, flags(ln)
 
-    ln, _ = jax.lax.while_loop(lambda st: jnp.any(st[1]), body,
+    ln, _ = jax.lax.while_loop(lambda st: jnp.any(st[1] != 0), body,
                                (ln, flags(ln)))
     slot = jnp.where(keep, ln, 0)
     pos_ref[...] = jnp.where(slot_iota == slot, px, pos)
@@ -177,7 +178,7 @@ def _disjoint_kernel(y_ref, cin, brk_ref, a_ref, v_ref, cout,
     def step(j, _):
         t_loc = ti * bt + j
         t = t_loc.astype(jnp.float32)
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))  # (1, BS)
+        yt = y_ref[pl.ds(j, 1), :]  # (1, BS)
         is_first = started[...] == 0
 
         rs, rl = run_start[...], runl[...]
@@ -200,9 +201,9 @@ def _disjoint_kernel(y_ref, cin, brk_ref, a_ref, v_ref, cout,
         a_out = jnp.where(rl >= 2, am, 0.0)
         v_out = jnp.where(rl >= 2, vm, py)
 
-        pl.store(brk_ref, (pl.ds(j, 1), slice(None)), brk.astype(jnp.int8))
-        pl.store(a_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, a_out, 0.0))
-        pl.store(v_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, v_out, 0.0))
+        brk_ref[pl.ds(j, 1), :] = brk.astype(brk_ref.dtype)
+        a_ref[pl.ds(j, 1), :] = jnp.where(brk, a_out, 0.0)
+        v_ref[pl.ds(j, 1), :] = jnp.where(brk, v_out, 0.0)
 
         # --- tangent retightening over the run's convex chains -----------
         second = rl == 1

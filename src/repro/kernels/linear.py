@@ -65,12 +65,13 @@ def _linear_kernel(y_ref, cin, brk_ref, a_ref, b_ref,
         vb[...] = cin[8:9, :]
         ring[...] = cin[_HEAD_ROWS:_HEAD_ROWS + W, :]
 
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, (W, 1), 0)
+    slot_iota = jax.lax.broadcasted_iota(
+        jnp.int32, (W, 1), 0).astype(jnp.float32)
 
     def step(j, _):
         t_loc = ti * bt + j
         t = t_loc.astype(jnp.float32)
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))  # (1, BS)
+        yt = y_ref[pl.ds(j, 1), :]  # (1, BS)
         is_first = started[...] == 0
 
         rs, n0 = run_start[...], nn[...]
@@ -109,9 +110,9 @@ def _linear_kernel(y_ref, cin, brk_ref, a_ref, b_ref,
 
         # (v_a, v_v): last valid fit as (slope, value at previous point) —
         # exactly the anchored output form for a break at t-1.
-        pl.store(brk_ref, (pl.ds(j, 1), slice(None)), brk.astype(jnp.int8))
-        pl.store(a_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, v_a, 0.0))
-        pl.store(b_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, v_v, 0.0))
+        brk_ref[pl.ds(j, 1), :] = brk.astype(brk_ref.dtype)
+        a_ref[pl.ds(j, 1), :] = jnp.where(brk, v_a, 0.0)
+        b_ref[pl.ds(j, 1), :] = jnp.where(brk, v_v, 0.0)
 
         restart = brk | is_first
         run_start[...] = jnp.where(restart, t, rs)
@@ -124,7 +125,7 @@ def _linear_kernel(y_ref, cin, brk_ref, a_ref, b_ref,
         # value of the (new) valid fit at the *current* point t.
         vb[...] = jnp.where(restart, yt, a_fit * rel + b_fit)
         started[...] = jnp.ones_like(started[...])
-        pl.store(ring, (pl.ds(jnp.mod(t_loc, W), 1), slice(None)), yt)
+        ring[pl.ds(jnp.mod(t_loc, W), 1), :] = yt
         return 0
 
     jax.lax.fori_loop(0, bt, step, 0)

@@ -73,13 +73,14 @@ def mixed_unpack_carry(carry: jax.Array, window: int):
             carry[15], carry[16], carry[17], carry[18])
 
 
-@functools.partial(jax.jit, static_argnames=("eps", "window", "t_last"))
-def mixed_flush_carry(carry: jax.Array, eps: float, window: int,
-                      t_last: int):
+@functools.partial(jax.jit, static_argnames=("eps", "max_run", "window",
+                                             "t_last"))
+def mixed_flush_carry(carry: jax.Array, eps: float, max_run: int,
+                      window: int, t_last: int):
     """Close the stream from a carry: the final join decision's event plus
     the trailing segment's line at launch-local ``t_last``."""
     eps_v = jnp.full((carry.shape[1],), eps, jnp.float32)
-    return _mixed_flush(eps_v, mixed_ring(window),
+    return _mixed_flush(eps_v, max_run, mixed_ring(window),
                         mixed_unpack_carry(carry, window), t_last)
 
 
@@ -116,13 +117,14 @@ def _mixed_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         p_vmid[...] = cin[18:19, :]
         ring[...] = cin[_HEAD_ROWS:_HEAD_ROWS + W2, :]
 
-    slot_iota = jax.lax.broadcasted_iota(jnp.float32, (W2, 1), 0)
+    slot_iota = jax.lax.broadcasted_iota(
+        jnp.int32, (W2, 1), 0).astype(jnp.float32)
 
     def step(j, _):
         t_loc = ti * bt + j
         live = t_loc < t_stop
         t = t_loc.astype(jnp.float32)
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))  # (1, BS)
+        yt = y_ref[pl.ds(j, 1), :]  # (1, BS)
         is_first = started[...] == 0
 
         rs, rl = run_start[...], runl[...]
@@ -189,7 +191,10 @@ def _mixed_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         chi = jnp.where(rl >= 2, jnp.maximum(cv1, cv2), _BIG)
         jlo = jnp.maximum(plo, clo)
         jhi = jnp.minimum(phi, chi)
-        join = brk & (pe == 1) & (pi1 - pi0 >= 2.0) & (jlo <= jhi)
+        # A run already at max_run takes no join (it would hand the
+        # run one more point than the cap).
+        join = brk & ~cap_hit & (pe == 1) & (pi1 - pi0 >= 2.0) \
+            & (jlo <= jhi)
         vK = 0.5 * (jlo + jhi)
 
         m_jw = (p_r >= pi0) & (p_r < pi1 - 1.0)
@@ -204,14 +209,13 @@ def _mixed_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         vN = jnp.where(plk == 1, lk_vmid, pvm)
 
         evt = brk & (pe == 1)
-        pl.store(ev_ref, (pl.ds(j, 1), slice(None)), evt.astype(jnp.int8))
-        pl.store(pos_ref, (pl.ds(j, 1), slice(None)),
-                 jnp.where(evt, jnp.where(join, tau - 1.0, tau),
-                           0.0).astype(jnp.int32))
-        pl.store(a_ref, (pl.ds(j, 1), slice(None)),
-                 jnp.where(evt, jnp.where(join, aJ, aN), 0.0))
-        pl.store(v_ref, (pl.ds(j, 1), slice(None)),
-                 jnp.where(evt, jnp.where(join, vK - aJ, vN), 0.0))
+        ev_ref[pl.ds(j, 1), :] = evt.astype(ev_ref.dtype)
+        pos_ref[pl.ds(j, 1), :] = jnp.where(
+            evt, jnp.where(join, tau - 1.0, tau), 0.0).astype(jnp.int32)
+        a_ref[pl.ds(j, 1), :] = jnp.where(evt, jnp.where(join, aJ, aN),
+                                          0.0)
+        v_ref[pl.ds(j, 1), :] = jnp.where(evt,
+                                          jnp.where(join, vK - aJ, vN), 0.0)
 
         # The breaking run becomes prev: cache its free-case range/mid at
         # its last point (t - 1) before the stage-1 reset.
@@ -251,8 +255,8 @@ def _mixed_kernel(y_ref, cin, ev_ref, pos_ref, a_ref, v_ref, cout,
         p_vmid[...] = jnp.where(brk, np_vm, pvm)
         started[...] = jnp.where(upd, 1, started[...])
         row = pl.ds(jnp.mod(t_loc, W2), 1)
-        cur_row = pl.load(ring, (row, slice(None)))
-        pl.store(ring, (row, slice(None)), jnp.where(live, yt, cur_row))
+        cur_row = ring[row, :]
+        ring[row, :] = jnp.where(live, yt, cur_row)
         return 0
 
     jax.lax.fori_loop(0, bt, step, 0)

@@ -120,7 +120,7 @@ def _run_deferred(method, y, eps, max_run, window, block_s, block_t):
     ev, pos, ea, ev_v, carry = kernel_fn(
         yp.T, eps=float(eps), t_stop=T, max_run=max_run, window=W,
         block_s=block_s, block_t=block_t)
-    flush_evs = flush_fn(carry, float(eps), W, T - 1)
+    flush_evs = flush_fn(carry, float(eps), max_run, W, T - 1)
     return assemble_deferred(ev, pos, ea, ev_v, flush_evs, S, T)
 
 
@@ -167,7 +167,7 @@ def _pad_events(seg: SegmentOutput, block_s: int, block_t: int):
         out = jnp.full((Sp, Tp), fill, x.dtype)
         return out.at[:S, :T].set(x)
 
-    return (pad(breaks.astype(jnp.int8), 1), pad(a.astype(jnp.float32), 0.0),
+    return (pad(breaks.astype(jnp.int32), 1), pad(a.astype(jnp.float32), 0.0),
             pad(b.astype(jnp.float32), 0.0), S, T, Sp, Tp)
 
 
@@ -211,15 +211,15 @@ KERNEL_SEGMENTERS = {
 }
 
 # Deferred kernels: (kernel fn, init_carry(Sp, W), shift_carry(carry, m),
-# flush(carry, eps, W, t_last)).  Their events carry launch-local
+# flush(carry, eps, max_run, W, t_last)).  Their events carry launch-local
 # positions and the trailing flush runs on the host from the carry.
 DEFERRED_KERNELS = {
     "continuous": (continuous_pallas, cont_init_carry, cont_shift_carry,
-                   lambda carry, eps, w, t_last: continuous_flush_carry(
-                       carry, window=w, t_last=t_last)),
+                   lambda carry, eps, max_run, w, t_last:
+                   continuous_flush_carry(carry, window=w, t_last=t_last)),
     "mixed": (mixed_pallas, mixed_init_carry, mixed_shift_carry,
-              lambda carry, eps, w, t_last: mixed_flush_carry(
-                  carry, eps=eps, window=w, t_last=t_last)),
+              lambda carry, eps, max_run, w, t_last: mixed_flush_carry(
+                  carry, eps=eps, max_run=max_run, window=w, t_last=t_last)),
 }
 
 
@@ -444,8 +444,8 @@ class StreamingSegmenter:
                 launch_evs = None
             self._pend = []
             self._navail = 0
-            flush_evs = self._flush_fn(carry_out, self.eps, self.window,
-                                       r - 1)
+            flush_evs = self._flush_fn(carry_out, self.eps, self.max_run,
+                                       self.window, r - 1)
             out = self._deferred_collect(launch_evs, r, r,
                                          flush_evs=flush_evs)
             self._t += r

@@ -45,9 +45,9 @@ def _recon_kernel(brk_ref, a_ref, v_ref, cin, out_ref, cout, ca, cv, cd,
 
     def step(k, _):
         j = bt - 1 - k  # walk rows backwards
-        brk = pl.load(brk_ref, (pl.ds(j, 1), slice(None))) != 0
-        at = pl.load(a_ref, (pl.ds(j, 1), slice(None)))
-        vt = pl.load(v_ref, (pl.ds(j, 1), slice(None)))
+        brk = brk_ref[pl.ds(j, 1), :] != 0
+        at = a_ref[pl.ds(j, 1), :]
+        vt = v_ref[pl.ds(j, 1), :]
         # Anchored evaluation: carry (slope, value at anchor, distance to
         # anchor); y(t) = v - a * d.  No absolute-t products — float32 safe
         # at any stream length.
@@ -57,7 +57,7 @@ def _recon_kernel(brk_ref, a_ref, v_ref, cin, out_ref, cout, ca, cv, cd,
         ca[...] = new_a
         cv[...] = new_v
         cd[...] = new_d + 1.0
-        pl.store(out_ref, (pl.ds(j, 1), slice(None)), new_v - new_a * new_d)
+        out_ref[pl.ds(j, 1), :] = new_v - new_a * new_d
         return 0
 
     jax.lax.fori_loop(0, bt, step, 0)
@@ -84,10 +84,10 @@ def _recon_err_kernel(brk_ref, a_ref, v_ref, y_ref, cin, out_ref, err_ref,
 
     def step(k, _):
         j = bt - 1 - k
-        brk = pl.load(brk_ref, (pl.ds(j, 1), slice(None))) != 0
-        at = pl.load(a_ref, (pl.ds(j, 1), slice(None)))
-        vt = pl.load(v_ref, (pl.ds(j, 1), slice(None)))
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))
+        brk = brk_ref[pl.ds(j, 1), :] != 0
+        at = a_ref[pl.ds(j, 1), :]
+        vt = v_ref[pl.ds(j, 1), :]
+        yt = y_ref[pl.ds(j, 1), :]
         new_a = jnp.where(brk, at, ca[...])
         new_v = jnp.where(brk, vt, cv[...])
         new_d = jnp.where(brk, jnp.zeros_like(cd[...]), cd[...])
@@ -95,8 +95,8 @@ def _recon_err_kernel(brk_ref, a_ref, v_ref, y_ref, cin, out_ref, err_ref,
         cv[...] = new_v
         cd[...] = new_d + 1.0
         recon = new_v - new_a * new_d
-        pl.store(out_ref, (pl.ds(j, 1), slice(None)), recon)
-        pl.store(err_ref, (pl.ds(j, 1), slice(None)), jnp.abs(recon - yt))
+        out_ref[pl.ds(j, 1), :] = recon
+        err_ref[pl.ds(j, 1), :] = jnp.abs(recon - yt)
         return 0
 
     jax.lax.fori_loop(0, bt, step, 0)
@@ -126,7 +126,8 @@ def reconstruct_error_pallas(brk_t: jax.Array, a_t: jax.Array,
     kernel = functools.partial(_recon_err_kernel, bt=block_t, nt=nt)
     scratch = [((1, block_s), jnp.float32)] * 3
     out, err, carry_out = launch_segmenter(
-        kernel, (brk_t, a_t, v_t, y_t), block_s=block_s, block_t=block_t,
+        kernel, (brk_t.astype(jnp.int32), a_t, v_t, y_t),
+        block_s=block_s, block_t=block_t,
         out_dtypes=(a_t.dtype, a_t.dtype), scratch=scratch,
         reverse_time=True, carry=carry)
     return out, err, carry_out
@@ -138,7 +139,9 @@ def reconstruct_pallas(brk_t: jax.Array, a_t: jax.Array, v_t: jax.Array,
                        carry: jax.Array | None = None):
     """Time-major (Tp, Sp) breaks/a/v -> (Tp, Sp) reconstructed values.
 
-    Returns ``(out, carry_out)``; pass the carry-out of a later-in-time
+    Break flags of any integer or bool dtype are read as 32-bit rows
+    (the per-step dynamic row load needs a 32-bit tile).  Returns
+    ``(out, carry_out)``; pass the carry-out of a later-in-time
     slab as ``carry`` to reconstruct the preceding slab (reverse-chunked
     streaming).  ``carry=None`` starts from the stream tail.
     """
@@ -149,7 +152,8 @@ def reconstruct_pallas(brk_t: jax.Array, a_t: jax.Array, v_t: jax.Array,
     kernel = functools.partial(_recon_kernel, bt=block_t, nt=nt)
     scratch = [((1, block_s), jnp.float32)] * 3
     # Sequential dim walks time blocks in reverse (reverse_time index map).
-    out, carry_out = launch_segmenter(kernel, (brk_t, a_t, v_t),
+    out, carry_out = launch_segmenter(kernel,
+                                      (brk_t.astype(jnp.int32), a_t, v_t),
                                       block_s=block_s, block_t=block_t,
                                       out_dtypes=(a_t.dtype,),
                                       scratch=scratch,

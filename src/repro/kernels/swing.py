@@ -51,7 +51,7 @@ def _swing_kernel(y_ref, cin, brk_ref, a_ref, v_ref, cout,
 
     def step(j, _):
         t_loc = ti * bt + j
-        yt = pl.load(y_ref, (pl.ds(j, 1), slice(None)))  # (1, BS)
+        yt = y_ref[pl.ds(j, 1), :]  # (1, BS)
         is_first = started[...] == 0
 
         o_d, o_y = od[...], oy[...]
@@ -72,9 +72,9 @@ def _swing_kernel(y_ref, cin, brk_ref, a_ref, v_ref, cout,
         a_out = 0.5 * (s_lo + s_hi)
         v_out = o_y + a_out * (o_d - 1.0)   # knot at t-1 (on the old line)
 
-        pl.store(brk_ref, (pl.ds(j, 1), slice(None)), brk.astype(jnp.int8))
-        pl.store(a_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, a_out, 0.0))
-        pl.store(v_ref, (pl.ds(j, 1), slice(None)), jnp.where(brk, v_out, 0.0))
+        brk_ref[pl.ds(j, 1), :] = brk.astype(brk_ref.dtype)
+        a_ref[pl.ds(j, 1), :] = jnp.where(brk, a_out, 0.0)
+        v_ref[pl.ds(j, 1), :] = jnp.where(brk, v_out, 0.0)
 
         # Restart from the knot (t-1, v_out); re-add this point (dt == 1).
         b_lo = yt - eps - v_out

@@ -111,21 +111,9 @@ def make_train_step(api: ModelAPI, tcfg: TrainConfig,
     assert mesh is not None and "pod" in mesh.axis_names, \
         "pla grad mode needs a mesh with a 'pod' axis"
 
-    # New JAX: manual over 'pod' only, the other axes stay automatically
-    # sharded.  JAX 0.4.x cannot mix manual and auto axes once the body
-    # scans (XLA partitioner CHECK — see compat.sharding), so there we go
-    # manual over the *whole* mesh and take the exact data-parallel mean
-    # over the non-pod axes ourselves before the compressed pod exchange.
-    partial_auto = compat_sharding.partial_auto_shard_map_supported()
-    manual_axes = {"pod"} if partial_auto else set(mesh.axis_names)
-    dp_axes = () if partial_auto else \
-        tuple(a for a in mesh.axis_names if a != "pod")
-
+    # Manual over 'pod' only; the other axes stay automatically sharded.
     def pod_local(params, opt, ef, batch, step_idx):
         loss, grads = _accum_grads(loss_fn, params, batch, tcfg.grad_accum)
-        if dp_axes:
-            loss = jax.lax.pmean(loss, dp_axes)
-            grads = jax.tree.map(lambda g: jax.lax.pmean(g, dp_axes), grads)
         mean_g, new_ef, stats = pod_compressed_mean(grads, ef, tcfg.pla,
                                                     axis_name="pod")
         params, opt, st = adamw_update(mean_g, opt, params,
@@ -140,21 +128,16 @@ def make_train_step(api: ModelAPI, tcfg: TrainConfig,
 
     replicated = lambda tree: jax.tree.map(lambda _: P(), tree)
 
-    # Batch dim shards over 'pod' (partial-auto leaves the rest to XLA)
-    # or over every manual axis (full-manual fallback).
-    batch_axes = ("pod",) if partial_auto else \
-        ("pod",) + dp_axes
-
     def step(params, opt, ef, batch, step_idx):
         batch_specs = jax.tree.map(
-            lambda x: P(*((batch_axes,) + (None,) * (x.ndim - 1))), batch)
+            lambda x: P(*(("pod",) + (None,) * (x.ndim - 1))), batch)
         fn = compat_sharding.shard_map(
             pod_local, mesh=mesh,
             in_specs=(replicated(params), replicated(opt), replicated(ef),
                       batch_specs, P()),
             out_specs=(replicated(params), replicated(opt), replicated(ef),
                        {"loss": P(), "grad_norm": P(), "wire_bytes": P()}),
-            axis_names=manual_axes, check=False)
+            axis_names={"pod"}, check=False)
         return fn(params, opt, ef, batch, step_idx)
 
     return step

@@ -34,21 +34,15 @@ Layers:
   bytes per stream, bit-identical to the offline encode of the whole
   stream (PR-2 carry contract per shard).
 
-shard_map compatibility (ROADMAP "Supported JAX versions"): on new JAX
-the pipeline is manual over ``"streams"`` only (``axis_names=``), leaving
-any other mesh axes auto.  JAX 0.4.x cannot mix manual and auto axes once
-the body scans (the segmenters are ``lax.scan``s) — there
-``compat.sharding.partial_auto_shard_map_supported()`` gates a
-**full-manual fallback**: the mesh must be 1-D over ``"streams"`` and the
-body stays psum-shaped (scalar reductions only), which this pipeline is
-by construction.
+Every shard_map here is manual over ``"streams"`` only, leaving any other
+mesh axes auto.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional
 
 import jax
 import jax.numpy as jnp
@@ -65,13 +59,13 @@ from repro.core.protocol_engine import (ENGINE_PROTOCOLS,
                                         encode_batch,
                                         metrics_from_descriptors,
                                         protocol_descriptors)
-from repro.core.wire_device import DeviceProtocolEmitter
+from repro.core.protocol_engine import ProtocolEmitter
 from repro.core.protocols import PROTOCOL_CAPS
 from repro.core.jax_pla import SegmentOutput
 
-__all__ = ["FLEET_AXIS", "FleetPointMetrics", "FleetStream", "FleetWire",
+__all__ = ["FLEET_AXIS", "FleetPointMetrics", "FleetStream",
            "fleet_mesh", "fleet_shard", "fleet_point_metrics",
-           "fleet_encode", "fleet_wire", "pad_to_mesh"]
+           "fleet_encode", "pad_to_mesh"]
 
 FLEET_AXIS = "streams"
 
@@ -88,27 +82,12 @@ def fleet_mesh(n_devices: Optional[int] = None, *,
     return cs.make_mesh((len(devs),), (FLEET_AXIS,), devices=devs)
 
 
-def _mesh_axes(mesh: jax.sharding.Mesh) -> Tuple[Optional[Tuple[str, ...]],
-                                                 int]:
-    """(manual axis_names for shard_map, shard count) for a fleet mesh.
-
-    New JAX: manual over ``"streams"`` only — extra mesh axes stay auto.
-    0.4.x (no partial-auto once the body scans): full manual, which
-    requires the mesh to be exactly 1-D over ``"streams"``.
-    """
+def _shard_count(mesh: jax.sharding.Mesh) -> int:
+    """Number of stream shards of a fleet mesh (its ``"streams"`` size)."""
     if FLEET_AXIS not in mesh.axis_names:
         raise ValueError(f"fleet mesh needs a {FLEET_AXIS!r} axis; "
                          f"got {tuple(mesh.axis_names)}")
-    d = int(mesh.shape[FLEET_AXIS])
-    if cs.partial_auto_shard_map_supported():
-        return (FLEET_AXIS,), d
-    if tuple(mesh.axis_names) != (FLEET_AXIS,):
-        raise ValueError(
-            "this JAX cannot mix manual and auto shard_map axes over a "
-            "scanning body (partial_auto_shard_map_supported() is False): "
-            f"the fleet mesh must be 1-D over {FLEET_AXIS!r}, got "
-            f"{tuple(mesh.axis_names)}")
-    return None, d
+    return int(mesh.shape[FLEET_AXIS])
 
 
 def _check_shards(S: int, d: int) -> None:
@@ -132,7 +111,7 @@ def pad_to_mesh(y, mesh: jax.sharding.Mesh):
     outputs back to ``[:S]``; fleet byte totals include the (tiny,
     deterministic) padding contribution, so compare like against like.
     """
-    _, d = _mesh_axes(mesh)
+    d = _shard_count(mesh)
     y = jnp.asarray(y, jnp.float32)
     S = y.shape[0]
     pad = -S % d
@@ -144,10 +123,21 @@ def pad_to_mesh(y, mesh: jax.sharding.Mesh):
 
 def fleet_shard(y, mesh: jax.sharding.Mesh) -> jax.Array:
     """Place an ``(S, T)`` batch on the mesh, streams over devices."""
-    _, d = _mesh_axes(mesh)
+    d = _shard_count(mesh)
     y = jnp.asarray(y, jnp.float32)
     _check_shards(y.shape[0], d)
     return jax.device_put(y, NamedSharding(mesh, P(FLEET_AXIS, None)))
+
+
+def _max_run(protocol: str, max_run: Optional[int]) -> int:
+    """Points per segment: the protocol's counter cap by default, never
+    more."""
+    cap = PROTOCOL_CAPS[protocol]
+    max_run = max_run or cap or 256
+    if cap is not None and max_run > cap:
+        raise ValueError(f"max_run={max_run} exceeds the {protocol!r} "
+                         f"counter cap ({cap})")
+    return max_run
 
 
 @dataclasses.dataclass
@@ -181,7 +171,6 @@ def _fleet_pipeline(mesh: jax.sharding.Mesh, method: str, protocol: str,
                     knot_kind: str, max_run: int, burst_cap: int):
     """Build + cache the jitted shard_map'd device pipeline for one
     (mesh, method, protocol) configuration."""
-    axis_names, _ = _mesh_axes(mesh)
     segment = BATCHED_SEGMENTERS[method]
 
     def body(y_blk, eps_blk):
@@ -208,7 +197,7 @@ def _fleet_pipeline(mesh: jax.sharding.Mesh, method: str, protocol: str,
             P(FLEET_AXIS),                # (1,) per shard -> (D,)
             P(), P(),                     # psum/pmean: replicated
         ),
-        axis_names=axis_names)
+        axis_names=(FLEET_AXIS,))
     return jax.jit(sharded)
 
 
@@ -232,16 +221,12 @@ def fleet_point_metrics(y, eps, method: str, protocol: str, *,
         raise ValueError(f"no batched segmenter for {method!r}; "
                          f"have {sorted(BATCHED_SEGMENTERS)}")
     mesh = mesh if mesh is not None else fleet_mesh()
-    _, d_count = _mesh_axes(mesh)
+    d_count = _shard_count(mesh)
     y = np.asarray(y, np.float32)
     S, T = y.shape
     _check_shards(S, d_count)
     knot_kind = knot_kind or METHOD_KNOT_KINDS.get(method, "disjoint")
-    cap = PROTOCOL_CAPS[protocol]
-    max_run = max_run or cap or 256
-    if cap is not None and max_run > cap:
-        raise ValueError(f"max_run={max_run} exceeds the {protocol!r} "
-                         f"counter cap ({cap})")
+    max_run = _max_run(protocol, max_run)
     eps_arr = jnp.broadcast_to(jnp.asarray(eps, jnp.float32), (S,))
     fn = _fleet_pipeline(mesh, method, protocol, knot_kind, int(max_run),
                          int(burst_cap))
@@ -261,273 +246,12 @@ def fleet_point_metrics(y, eps, method: str, protocol: str, *,
 
 
 def fleet_encode(fm: FleetPointMetrics, y, *, t0: float = 0.0,
-                 dt: float = 1.0, burst_cap: int = 127,
-                 device: bool = False) -> List:
+                 dt: float = 1.0, burst_cap: int = 127) -> List:
     """Wire-encode every stream of a fleet result, bit-identical to the
-    legacy codecs.  ``device=True`` packs the bytes on device
-    (:func:`repro.core.wire_device.pack_batch_device`) and copies only
-    finished blobs to the host; the default is the vectorized host packer
+    legacy codecs, with the vectorized host packer
     (:func:`repro.core.protocol_engine.encode_batch`)."""
-    if device:
-        from repro.core.wire_device import pack_batch_device
-        return pack_batch_device(fm.seg, y, fm.protocol, fm.knot_kind,
-                                 t0=t0, dt=dt, burst_cap=burst_cap)
     return encode_batch(fm.seg, y, fm.protocol, fm.knot_kind, t0=t0, dt=dt,
                         burst_cap=burst_cap)
-
-
-# ---------------------------------------------------------------------------
-# Lean ingest: segment -> device wire pack, no descriptor materialization
-# ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class FleetWire:
-    """A fleet batch segmented and wire-packed entirely on device.
-
-    The production transmit path: no §4.2 metric surfaces, no
-    ``(S, T)`` descriptor materialization — just the segmentation and the
-    finished per-stream wire blobs (bit-identical to
-    :func:`~repro.core.protocol_engine.encode_batch`), with the per-shard
-    and ``psum``'d fleet byte totals computed on device.
-    """
-
-    method: str
-    protocol: str
-    knot_kind: str
-    n_devices: int
-    seg: SegmentOutput            # (S, T); device-sharded when sharded
-    blobs: List                   # per-stream bytes (pairs: twostreams)
-    nbytes: np.ndarray            # (S,) per-stream wire totals
-    shard_nbytes: np.ndarray      # (D,) per-shard totals, gather-free
-    fleet_nbytes: int             # psum over shards
-
-
-@functools.lru_cache(maxsize=None)
-def _fleet_segment(mesh: jax.sharding.Mesh, method: str, max_run: int):
-    """Segmentation-only shard_map launch (f32, identical to the batched
-    engine — the wire launches below run under x64 and must not perturb
-    the segmenter's arithmetic).  Also returns the shard's densest
-    break count (sizes the wire launches' static ``E`` bucket)."""
-    axis_names, _ = _mesh_axes(mesh)
-    segment = BATCHED_SEGMENTERS[method]
-
-    def body(y_blk, eps_blk):
-        seg = segment(y_blk, eps_blk, max_run=max_run)
-        brk = seg.breaks.at[:, -1].set(True)
-        nev = jnp.max(jnp.sum(brk.astype(jnp.int32), axis=1))
-        return seg, nev[None]
-
-    sharded = cs.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(FLEET_AXIS, None), P(FLEET_AXIS)),
-        out_specs=(SegmentOutput(*([P(FLEET_AXIS, None)] * 3)),
-                   P(FLEET_AXIS)),
-        axis_names=axis_names)
-    return jax.jit(sharded)
-
-
-@functools.lru_cache(maxsize=None)
-def _fleet_wire_stats(mesh: jax.sharding.Mesh, protocol: str,
-                      knot_kind: str, burst_cap: int, t0: float,
-                      dt: float, E: int):
-    """Bucket-sizing launch: per-shard (max stream bytes, max record
-    size) per sub-protocol — two scalars per shard, nothing gathered."""
-    from repro.core import wire_device as wd
-    axis_names, _ = _mesh_axes(mesh)
-    subs = wd._sub_protocols(protocol)
-
-    def body(brk, a, v, y_blk):
-        S = brk.shape[0]
-        brk = brk.at[:, -1].set(True)
-        state = wd.wire_init_state(S)
-        outs = []
-        for sub in subs:
-            _, _, nbmax, szmax, _ = wd._wire_plan(
-                brk, a, v, y_blk, jnp.int64(0), state, jnp.int64(0),
-                protocol=sub, knot_kind=knot_kind, close=True, t0=t0,
-                dt=dt, burst_cap=burst_cap, E=E)
-            outs.append(jnp.stack([nbmax.astype(jnp.int64),
-                                   szmax.astype(jnp.int64)])[None])
-        return tuple(outs)
-
-    sharded = cs.shard_map(
-        body, mesh=mesh, in_specs=(P(FLEET_AXIS, None),) * 4,
-        out_specs=tuple(P(FLEET_AXIS) for _ in subs),
-        axis_names=axis_names)
-    return jax.jit(sharded)
-
-
-@functools.lru_cache(maxsize=None)
-def _fleet_wire_pack(mesh: jax.sharding.Mesh, protocol: str, knot_kind: str,
-                     burst_cap: int, t0: float, dt: float, E: int,
-                     buckets):
-    """Pack launch: every shard plans, renders and assembles its streams'
-    wire bytes on device (``wire_device._wire_plan`` + ``_wire_emit``);
-    the only cross-device traffic is the scalar ``psum`` of the byte
-    totals."""
-    from repro.core import wire_device as wd
-    axis_names, _ = _mesh_axes(mesh)
-    subs = wd._sub_protocols(protocol)
-
-    def body(brk, a, v, y_blk):
-        S = brk.shape[0]
-        brk = brk.at[:, -1].set(True)
-        state = wd.wire_init_state(S)
-        outs = []
-        shard_nb = jnp.zeros((), jnp.int64)
-        for sub, (K, MB) in zip(subs, buckets):
-            plan, sz, _, _, _ = wd._wire_plan(
-                brk, a, v, y_blk, jnp.int64(0), state, jnp.int64(0),
-                protocol=sub, knot_kind=knot_kind, close=True, t0=t0,
-                dt=dt, burst_cap=burst_cap, E=E)
-            buf, nb = wd._wire_emit(
-                plan, sz, y_blk, jnp.int64(0), protocol=sub,
-                knot_kind=knot_kind, close=True, t0=t0, dt=dt,
-                burst_cap=burst_cap, K=K, MB=MB)
-            outs.extend([buf, nb.astype(jnp.int64)])
-            shard_nb = shard_nb + jnp.sum(nb).astype(jnp.int64)
-        fleet_nb = jax.lax.psum(shard_nb, FLEET_AXIS)
-        return tuple(outs) + (shard_nb[None], fleet_nb)
-
-    row = P(FLEET_AXIS)
-    out_specs = tuple(spec for _ in subs
-                      for spec in (P(FLEET_AXIS, None), row)) \
-        + (P(FLEET_AXIS), P())
-    sharded = cs.shard_map(
-        body, mesh=mesh,
-        in_specs=(P(FLEET_AXIS, None),) * 4,
-        out_specs=out_specs, axis_names=axis_names)
-    return jax.jit(sharded)
-
-
-@functools.lru_cache(maxsize=None)
-def _fused_segment(method: str, max_run: int):
-    """One-launch segment + forced trailing break + densest break count
-    (f32; the count sizes the wire launches' static ``E`` bucket)."""
-    segment = BATCHED_SEGMENTERS[method]
-
-    @jax.jit
-    def run(ys, eps):
-        seg = segment(ys, eps, max_run=max_run)
-        brk = seg.breaks.at[:, -1].set(True)
-        return seg, brk, jnp.max(jnp.sum(brk, axis=1, dtype=jnp.int32))
-    return run
-
-
-def _fused_wire_launches(seg, brk, E, ys, subs, knot_kind: str,
-                         burst_cap: int, t0: float, dt: float):
-    """Full-batch plan + emit (no shard_map) for every sub-protocol;
-    returns ``[(buf, nbytes), ...]`` as host arrays."""
-    from jax.experimental import enable_x64
-    from repro.core import wire_device as wd
-    with enable_x64():
-        state = wd.wire_init_state(brk.shape[0])
-        outs = []
-        for sub in subs:
-            plan, sz, nbmax, szmax, _ = wd._wire_plan(
-                brk, seg.a, seg.v, ys, jnp.int64(0), state, jnp.int64(0),
-                protocol=sub, knot_kind=knot_kind, close=True, t0=t0,
-                dt=dt, burst_cap=burst_cap, E=E)
-            buf, nbytes = wd._wire_emit(
-                plan, sz, ys, jnp.int64(0), protocol=sub,
-                knot_kind=knot_kind, close=True, t0=t0, dt=dt,
-                burst_cap=burst_cap, K=wd._bucket(int(szmax), 8),
-                MB=wd._bucket(int(nbmax), 8))
-            outs.append((np.asarray(buf), np.asarray(nbytes, np.int64)))
-    return outs
-
-
-def fleet_wire(y, eps, method: str, protocol: str, *,
-               mesh: Optional[jax.sharding.Mesh] = None,
-               knot_kind: Optional[str] = None,
-               max_run: Optional[int] = None, burst_cap: int = 127,
-               t0: float = 0.0, dt: float = 1.0,
-               sharded: Optional[bool] = None) -> FleetWire:
-    """Segment + wire-pack a fleet batch entirely on device.
-
-    The lean end-to-end ingest path: one segmentation launch (f32, same
-    breaks as :func:`fleet_point_metrics`), one bucket-sizing launch
-    (two scalars per shard back to the host), one pack launch — the
-    bytes leave the devices only as finished ``(buf, nbytes)`` blobs.
-    Output bytes are bit-identical per stream to
-    :func:`~repro.core.protocol_engine.encode_batch` on the one-shot
-    segmentation.
-
-    ``sharded`` picks the launch granularity.  The default (``None``)
-    shards over the mesh only when it spans real accelerators: on an
-    all-CPU mesh (e.g. ``--xla_force_host_platform_device_count`` fake
-    devices) every "device" is the same host CPU, shard_map partitions
-    execute *serially*, and splitting the batch only multiplies launch
-    overhead — there the identical array program runs full-batch
-    instead (``sharded=False``), still reporting per-shard byte totals.
-    """
-    from repro.core import wire_device as wd
-    if protocol not in ENGINE_PROTOCOLS:
-        raise ValueError(f"unknown protocol {protocol!r}; "
-                         f"have {sorted(ENGINE_PROTOCOLS)}")
-    if method not in BATCHED_SEGMENTERS:
-        raise ValueError(f"no batched segmenter for {method!r}; "
-                         f"have {sorted(BATCHED_SEGMENTERS)}")
-    mesh = mesh if mesh is not None else fleet_mesh()
-    _, d_count = _mesh_axes(mesh)
-    y = np.asarray(y, np.float32)
-    S, T = y.shape
-    _check_shards(S, d_count)
-    knot_kind = knot_kind or METHOD_KNOT_KINDS.get(method, "disjoint")
-    cap = PROTOCOL_CAPS[protocol]
-    max_run = max_run or cap or 256
-    if cap is not None and max_run > cap:
-        raise ValueError(f"max_run={max_run} exceeds the {protocol!r} "
-                         f"counter cap ({cap})")
-    eps_arr = jnp.broadcast_to(jnp.asarray(eps, jnp.float32), (S,))
-    subs = wd._sub_protocols(protocol)
-    if sharded is None:
-        sharded = any(d.platform != "cpu" for d in mesh.devices.flat)
-
-    if not sharded:
-        ys = jnp.asarray(y)
-        seg, brk, nev = _fused_segment(method, int(max_run))(ys, eps_arr)
-        per = _fused_wire_launches(seg, brk, wd._bucket(int(nev)), ys,
-                                   subs, knot_kind, int(burst_cap),
-                                   float(t0), float(dt))
-        per_sub = [wd._slice_bytes(buf, nb) for buf, nb in per]
-        nbytes = sum(nb for _, nb in per)
-        shard_nbytes = nbytes.reshape(d_count, S // d_count).sum(axis=1)
-        blobs = (list(zip(*per_sub)) if protocol == "twostreams"
-                 else per_sub[0])
-        return FleetWire(
-            method=method, protocol=protocol, knot_kind=knot_kind,
-            n_devices=d_count, seg=seg, blobs=blobs, nbytes=nbytes,
-            shard_nbytes=shard_nbytes,
-            fleet_nbytes=int(shard_nbytes.sum()))
-
-    from jax.experimental import enable_x64
-    with cs.use_mesh(mesh):
-        ys = fleet_shard(y, mesh)
-        seg, nev = _fleet_segment(mesh, method, int(max_run))(ys, eps_arr)
-        E = wd._bucket(int(np.max(np.asarray(nev))))
-        with enable_x64():
-            pre = _fleet_wire_stats(
-                mesh, protocol, knot_kind, int(burst_cap), float(t0),
-                float(dt), E)(seg.breaks, seg.a, seg.v, ys)
-            buckets = tuple(
-                (wd._bucket(int(np.max(p[:, 1])), 8),
-                 wd._bucket(int(np.max(p[:, 0])), 8))
-                for p in map(np.asarray, pre))
-            outs = _fleet_wire_pack(
-                mesh, protocol, knot_kind, int(burst_cap), float(t0),
-                float(dt), E, buckets)(seg.breaks, seg.a, seg.v, ys)
-    per_sub = [wd._slice_bytes(np.asarray(outs[2 * i]),
-                               np.asarray(outs[2 * i + 1]))
-               for i in range(len(subs))]
-    blobs = list(zip(*per_sub)) if protocol == "twostreams" else per_sub[0]
-    nbytes = sum(np.asarray(outs[2 * i + 1], np.int64)
-                 for i in range(len(subs)))
-    return FleetWire(
-        method=method, protocol=protocol, knot_kind=knot_kind,
-        n_devices=d_count, seg=seg, blobs=blobs, nbytes=nbytes,
-        shard_nbytes=np.asarray(outs[-2]),
-        fleet_nbytes=int(outs[-1]))
 
 
 # ---------------------------------------------------------------------------
@@ -540,14 +264,13 @@ class FleetStream:
     The stream fleet is partitioned row-wise into one shard per device;
     each shard owns a :class:`~repro.kernels.ops.StreamingSegmenter`
     (kernel carry state pinned to that device via ``jax.device_put`` of
-    its chunks) and a
-    :class:`~repro.core.wire_device.DeviceProtocolEmitter` (the
-    device-resident wire packer: value ring, codec state and byte
-    assembly all stay on device, so pushes never bounce through host
-    numpy).  ``push`` fans the chunk out shard-by-shard
-    and returns the newly wire-ready bytes per stream — for the deferred
-    methods (continuous/mixed) a shard's emission lags its released
-    columns, exactly like the single-device engine.  Concatenating all
+    its chunks) and a host
+    :class:`~repro.core.protocol_engine.ProtocolEmitter`, whose float64
+    codec arithmetic is the legacy codecs' own on every platform.
+    ``push`` fans the chunk out shard-by-shard and returns the newly
+    wire-ready bytes per stream — for the deferred methods
+    (continuous/mixed) a shard's emission lags its released columns,
+    exactly like the single-device engine.  Concatenating all
     ``push`` outputs with the ``finish`` output is bit-identical per
     stream to the offline
     :func:`~repro.core.protocol_engine.encode_batch` of the one-shot
@@ -579,20 +302,15 @@ class FleetStream:
         self.n_streams = n_streams
         self.knot_kind = knot_kind or METHOD_KNOT_KINDS.get(method,
                                                             "disjoint")
-        cap = PROTOCOL_CAPS[protocol]
-        max_run = max_run or cap or 256
-        if cap is not None and max_run > cap:
-            raise ValueError(f"max_run={max_run} exceeds the {protocol!r} "
-                             f"counter cap ({cap})")
+        max_run = _max_run(protocol, max_run)
         self._rows = n_streams // d
         self._segs = [StreamingSegmenter(method, self._rows, eps,
                                          max_run=max_run, window=window,
                                          **segmenter_kw)
                       for _ in range(d)]
-        self._ems = [DeviceProtocolEmitter(protocol, self._rows,
-                                           knot_kind=self.knot_kind, t0=t0,
-                                           dt=dt, burst_cap=burst_cap,
-                                           max_run=max_run)
+        self._ems = [ProtocolEmitter(protocol, self._rows,
+                                     knot_kind=self.knot_kind, t0=t0, dt=dt,
+                                     burst_cap=burst_cap)
                      for _ in range(d)]
         self.shard_bytes = np.zeros(d, np.int64)
         self.pushed = 0
@@ -635,14 +353,12 @@ class FleetStream:
         shard_events = []
         for d, seg in enumerate(self._segs):
             rows = y[d * self._rows:(d + 1) * self._rows]
-            shard = jax.device_put(jnp.asarray(rows), self.devices[d])
-            shard_events.append((shard, seg.push(shard)))
+            shard = jax.device_put(rows, self.devices[d])
+            shard_events.append((rows, seg.push(shard)))
         out: List = []
-        for d, (em, (shard, events)) in enumerate(zip(self._ems,
-                                                      shard_events)):
-            # The device emitter keeps the value ring + codec state on
-            # device: the chunk never bounces back through host numpy.
-            blobs = em.step_chunk(events, shard)
+        for d, (em, (rows, events)) in enumerate(zip(self._ems,
+                                                     shard_events)):
+            blobs = em.step_chunk(events, rows)
             self._account(d, blobs)
             out.extend(blobs)
         self.pushed += y.shape[1]

@@ -195,10 +195,9 @@ def window_aggregate(kind: str, covers: Sequence[Cover], eps,
     """Batched ``(value, error_bound)`` per stream over ``[lo, hi)``."""
     if kind not in ("sum", "avg", "min", "max", "count"):
         raise ValueError(f"unknown aggregate {kind!r}")
-    from jax.experimental import enable_x64
     eps = np.asarray(eps, np.float64)
     s, e, Ag, Bg, ap = _pad_stack(covers)
-    with enable_x64():
+    with jax.enable_x64(True):
         n, n_ax, total, _, vmin, vmax = (
             np.asarray(r) for r in _agg_core(
                 jnp.asarray(s), jnp.asarray(e), jnp.asarray(Ag),
@@ -229,10 +228,9 @@ def window_correlation(cov_x: Cover, cov_y: Cover, eps_x: float,
                        eps_y: float, lo: int, hi: int
                        ) -> Tuple[float, float]:
     """Pearson correlation over ``[lo, hi)`` with a closed-form bound."""
-    from jax.experimental import enable_x64
     b, e, Ax, Bx, Ay, By, apx, apy = _merge(cov_x, cov_y)
     E = _bucket(b.size)
-    with enable_x64():
+    with jax.enable_x64(True):
         res = _corr_core(
             jnp.asarray(_pad(b, E, np.int64)),
             jnp.asarray(_pad(e, E, np.int64)),

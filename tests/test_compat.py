@@ -1,12 +1,10 @@
-"""Unit tests for the JAX version-compatibility layer (repro.compat).
+"""Unit tests for the JAX API layer (repro.compat).
 
-Covers both sides of each API rename by monkeypatching the *other*
-spelling onto the installed JAX, so the suite exercises the new-JAX and
-old-JAX resolution paths regardless of which version is running.
+Monkeypatched spellings check that each wrapper forwards to the JAX
+attribute it names, resolved at call time.
 """
 
 import contextlib
-import dataclasses
 
 import jax
 import jax.numpy as jnp
@@ -18,51 +16,14 @@ from repro.compat import sharding as cs
 
 
 # ---------------------------------------------------------------------------
-# compat.pallas: compiler-params name resolution
+# compat.pallas: compiler params and interpret mode
 # ---------------------------------------------------------------------------
-
-@dataclasses.dataclass
-class _FakeParams:
-    """Stand-in compiler-params class with a restricted field set."""
-    dimension_semantics: tuple = ()
-    vmem_limit_bytes: int = 0
-
 
 def test_compiler_params_resolves_installed_spelling():
     from jax.experimental.pallas import tpu as pltpu
-    has_new = hasattr(pltpu, "CompilerParams")
-    has_old = hasattr(pltpu, "TPUCompilerParams")
-    assert has_new or has_old
-    expected = pltpu.CompilerParams if has_new else pltpu.TPUCompilerParams
-    assert cp.COMPILER_PARAMS_CLS is expected
     p = cp.tpu_compiler_params(dimension_semantics=("parallel", "arbitrary"))
-    assert isinstance(p, expected)
+    assert isinstance(p, pltpu.CompilerParams)
     assert tuple(p.dimension_semantics) == ("parallel", "arbitrary")
-
-
-def test_compiler_params_prefers_new_spelling(monkeypatch):
-    """If both spellings exist (transition versions), the new name wins."""
-    from jax.experimental.pallas import tpu as pltpu
-    monkeypatch.setattr(pltpu, "CompilerParams", _FakeParams, raising=False)
-    assert cp._resolve_compiler_params_cls() is _FakeParams
-
-
-def test_compiler_params_falls_back_to_old_spelling(monkeypatch):
-    from jax.experimental.pallas import tpu as pltpu
-    monkeypatch.delattr(pltpu, "CompilerParams", raising=False)
-    monkeypatch.setattr(pltpu, "TPUCompilerParams", _FakeParams,
-                        raising=False)
-    assert cp._resolve_compiler_params_cls() is _FakeParams
-
-
-def test_compiler_params_drops_unknown_fields(monkeypatch):
-    monkeypatch.setattr(cp, "COMPILER_PARAMS_CLS", _FakeParams)
-    p = cp.tpu_compiler_params(dimension_semantics=("parallel",),
-                               vmem_limit_bytes=7,
-                               some_future_knob=True)
-    assert p.dimension_semantics == ("parallel",)
-    assert p.vmem_limit_bytes == 7
-    assert not hasattr(p, "some_future_knob")
 
 
 def test_interpret_mode_on_cpu():
@@ -72,6 +33,14 @@ def test_interpret_mode_on_cpu():
         assert cp.interpret_mode() is True
 
 
+def test_interpret_mode_rejects_other_backends(monkeypatch):
+    """A backend that is neither the TPU nor the CPU is an error, never a
+    silent fall back to interpret mode."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "gpu")
+    with pytest.raises(RuntimeError, match="gpu"):
+        cp.interpret_mode()
+
+
 # ---------------------------------------------------------------------------
 # compat.sharding: AxisType / abstract mesh / make_mesh / use_mesh
 # ---------------------------------------------------------------------------
@@ -79,8 +48,7 @@ def test_interpret_mode_on_cpu():
 def test_axis_type_has_expected_members():
     for member in ("Auto", "Explicit", "Manual"):
         assert hasattr(cs.AxisType, member)
-    if cs._NATIVE_AXIS_TYPE is not None:
-        assert cs.AxisType is jax.sharding.AxisType
+    assert cs.AxisType is jax.sharding.AxisType
 
 
 def test_get_abstract_mesh_none_without_mesh():
@@ -135,16 +103,6 @@ def test_make_mesh_forwards_axis_types_when_supported(monkeypatch):
     assert seen["axis_types"] == (cs.AxisType.Auto,)
 
 
-def test_make_mesh_omits_axis_types_when_unsupported(monkeypatch):
-    def fake_make_mesh(axis_shapes, axis_names, *, devices=None):
-        return jax.sharding.Mesh(
-            np.asarray(jax.devices()[:1]).reshape(axis_shapes), axis_names)
-
-    monkeypatch.setattr(jax, "make_mesh", fake_make_mesh)
-    mesh = cs.make_mesh((1,), ("data",))  # must not raise TypeError
-    assert mesh.axis_names == ("data",)
-
-
 def test_use_mesh_prefers_set_mesh(monkeypatch):
     calls = []
 
@@ -176,63 +134,6 @@ def test_axis_size_inside_shard_map():
     cs.shard_map(f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
                  axis_names={"pod"}, check=False)(jnp.ones((1, 4)))
     assert sizes == [1]
-
-
-def test_partial_auto_capability_requires_axis_names_kwarg():
-    """The capability flag tracks the axis_names= rewrite of shard_map,
-    which is what fixed mixed manual/auto lowering (scan + all_gather
-    CHECK failures); a transitional jax.shard_map with legacy auto=
-    kwargs must NOT report support."""
-    import inspect
-
-    native = getattr(jax, "shard_map", None)
-    expected = native is not None and \
-        "axis_names" in inspect.signature(native).parameters
-    assert cs.partial_auto_shard_map_supported() == expected
-
-
-def test_partial_auto_capability_transitional_api(monkeypatch):
-    def transitional(f, *, mesh, in_specs, out_specs, check_rep=True,
-                     auto=frozenset()):
-        raise NotImplementedError
-
-    monkeypatch.setattr(jax, "shard_map", transitional, raising=False)
-    assert cs.partial_auto_shard_map_supported() is False
-
-
-def test_shard_map_translates_axis_names_on_transitional_api(monkeypatch):
-    """jax.shard_map taking auto= (not axis_names=) still gets the
-    complement translated, not a silently-dropped kwarg."""
-    seen = {}
-
-    def transitional(f, *, mesh, in_specs, out_specs, check_rep=True,
-                     auto=frozenset()):
-        seen["auto"] = auto
-        seen["check_rep"] = check_rep
-        return f
-
-    monkeypatch.setattr(jax, "shard_map", transitional, raising=False)
-
-    class FakeMesh:
-        axis_names = ("pod", "data")
-
-    cs.shard_map(lambda x: x, mesh=FakeMesh(), in_specs=None,
-                 out_specs=None, axis_names={"pod"}, check=False)
-    assert seen["auto"] == frozenset({"data"})
-    assert seen["check_rep"] is False
-
-
-def test_shard_map_legacy_kwarg_translation(monkeypatch):
-    """axis_names/check translate to auto/check_rep on 0.4.x-style APIs."""
-    if hasattr(jax, "shard_map"):
-        pytest.skip("installed JAX has the new spelling")
-    mesh = cs.make_mesh((1,), ("pod",))
-    from jax.sharding import PartitionSpec as P
-    fn = cs.shard_map(lambda x: jax.lax.psum(x, "pod"), mesh=mesh,
-                      in_specs=P("pod"), out_specs=P(),
-                      axis_names={"pod"}, check=False)
-    out = fn(jnp.ones((1, 4)))
-    np.testing.assert_allclose(np.asarray(out), np.ones((1, 4)))
 
 
 # ---------------------------------------------------------------------------

@@ -22,6 +22,7 @@ except ImportError:          # fixed-draw twins below still run
     HAVE_HYPOTHESIS = False
 
 from repro.core import jax_pla
+from repro.core.methods import run_mixed
 from repro.core.protocol_engine import (ENGINE_PROTOCOLS, ProtocolEmitter,
                                         encode_batch)
 from repro.core.protocols import (PROTOCOL_CAPS, decode_implicit,
@@ -191,6 +192,38 @@ def test_fixed_codec_roundtrip(protocol, method):
 def test_fixed_bursts_straddle_counter_cap():
     for seed, n, n_long in ((0, 300, 0), (1, 130, 2), (2, 399, 1)):
         check_bursts_straddle_counter_cap(seed, n, n_long)
+
+
+@pytest.mark.parametrize("protocol", ("twostreams", "singlestream",
+                                      "singlestreamv"))
+def test_mixed_segments_fit_counter_cap(protocol):
+    """A mixed join hands the previous run's last point to the next
+    segment; at max_run = the protocol's cap every segment must still fit
+    the counter, in the batched engine, the Pallas kernel and the
+    sequential reference."""
+    from repro.kernels.ops import KERNEL_SEGMENTERS
+    cap = PROTOCOL_CAPS[protocol]
+    T = 3 * cap + 50
+    # Two capped runs on one line join; the jump after them makes the next
+    # decision disjoint, so the joined run keeps the point it was handed.
+    ramp = 0.01 * np.arange(T, dtype=np.float32)
+    ramp[2 * cap:] += 100.0
+    y = np.concatenate([ramp[None, :], _walk(4, T, scale=0.05)])
+    eps = 0.5
+    seg = jax_pla.mixed_segment(y, np.float32(eps), max_run=cap)
+    kseg = KERNEL_SEGMENTERS["mixed"](y, eps, max_run=cap)
+    np.testing.assert_array_equal(np.asarray(kseg.breaks),
+                                  np.asarray(seg.breaks))
+    ts = np.arange(T, dtype=float)
+    for s in range(2):
+        ends = np.flatnonzero(np.asarray(seg.breaks[s]))
+        assert np.diff(ends, prepend=-1).max() <= cap, (protocol, s)
+        ref = run_mixed(ts, y[s].astype(float), eps, max_run=cap)
+        assert max(g.i1 - g.i0 for g in ref.segments) <= cap, (protocol, s)
+    blobs = encode_batch(seg, y, protocol, knot_kind="mixed")
+    for s in range(2):
+        dec = np.asarray(_decode(protocol, blobs[s], ts))
+        assert np.abs(dec - y[s]).max() <= eps * (1 + 1e-4) + 1e-4
 
 
 @pytest.mark.parametrize("method", sorted(SEGMENTERS))
