@@ -1,0 +1,9 @@
+"""Share of the traced serving window in which no operation ran on the
+chip: 100 * (1 - busy / window), busy being the union of the device
+operations' intervals."""
+
+
+def read(run):
+    if run.trace is None or "ticks" not in run.records:
+        return None
+    return 100.0 * run.trace.idle_share()
