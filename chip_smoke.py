@@ -60,9 +60,6 @@ STORE_STREAMS, N_QUERIES, QUERY_STREAMS = 4096, 16, 64
 MESH_COMBOS = ("A1", "Sw", "C")     # two-stream, joint-knot, deferred
 MESH_STREAMS = 8192        # fleet_point_metrics keeps ten (S, T) planes
 
-BACKEND_COMPILE = "/jax/core/compile/backend_compile_duration"
-CACHE_HITS = "/jax/compilation_cache/cache_hits"
-
 
 class CheckFailed(Exception):
     """A result disagreed with its reference."""
@@ -73,33 +70,24 @@ def check(ok, msg: str) -> None:
         raise CheckFailed(msg)
 
 
-class CompileMeter:
-    """Seconds spent in XLA compilation and persistent-cache hits, read
-    from JAX's monitoring events."""
+class PhaseClock:
+    """Wall time, and the compiles and persistent-cache hits that
+    ``bench/core/compile_meter.py``'s counter saw, over each phase."""
 
-    def __init__(self, jax):
-        self.compile_s = 0.0
-        self.hits = 0
-        jax.monitoring.register_event_duration_secs_listener(self._duration)
-        jax.monitoring.register_event_listener(self._event)
-
-    def _duration(self, event, duration, **_):
-        if event == BACKEND_COMPILE:
-            self.compile_s += duration
-
-    def _event(self, event, **_):
-        if event == CACHE_HITS:
-            self.hits += 1
+    def __init__(self, meter):
+        self.meter = meter
 
     def mark(self):
-        return time.perf_counter(), self.compile_s, self.hits
+        return time.perf_counter(), self.meter.snapshot()
 
     def line(self, name: str, mark, points: int, wire_bytes: int,
              extra: str = "") -> str:
-        t, c, h = mark
+        t, before = mark
+        now = self.meter.snapshot()
         return (f"[{name}] wall_s={time.perf_counter() - t:.3f} "
-                f"compile_s={self.compile_s - c:.3f} "
-                f"cache_hits={self.hits - h} points={points} "
+                f"compile_s={now['compile_s'] - before['compile_s']:.3f} "
+                f"cache_hits={now['cache_hits'] - before['cache_hits']} "
+                f"points={points} "
                 f"wire_bytes={wire_bytes}{' ' + extra if extra else ''}")
 
 
@@ -424,7 +412,8 @@ def main(argv=None) -> int:
     if not (ROOT / "src" / "repro").is_dir():
         print(f"chip_smoke.py: no src/repro beside {ROOT}", file=sys.stderr)
         return 2
-    sys.path.insert(0, str(ROOT / "src"))
+    sys.path[:0] = [str(ROOT), str(ROOT / "src")]
+    from bench.core.compile_meter import CompileMeter
     from repro.launch.compile_cache import enable_compile_cache
     cache_dir = enable_compile_cache()
     import jax
@@ -445,7 +434,7 @@ def main(argv=None) -> int:
     used = devices[:args.chips]
     print(f"devices: using {len(used)} of {len(devices)} {dev0.platform} "
           f"({dev0.device_kind}); compile cache: {cache_dir}", flush=True)
-    meter = CompileMeter(jax)
+    meter = PhaseClock(CompileMeter(jax))
     rng = np.random.default_rng(args.seed)
     try:
         check_compiled_kernels(jax)

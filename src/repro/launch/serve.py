@@ -138,7 +138,9 @@ def serve_fleet(args) -> None:
             print(f"tick {rep.tick:4d}: live={rep.live} "
                   f"consumed={rep.consumed} bytes={rep.nbytes} "
                   f"eps=[{rep.eps_lo:.3g}, {rep.eps_hi:.3g}]"
-                  f"{pool} shed={rep.shed_total}")
+                  f"{pool} shed={rep.shed_total} "
+                  f"wait={1e3 * rep.wait_mean_s:.2f}ms "
+                  f"(max {1e3 * rep.wait_max_s:.2f}ms)")
     dt_s = time.time() - t0
     print(f"served {total_points} points / {total_bytes} wire bytes "
           f"across {n_admitted} stream admissions in {dt_s:.2f}s "

@@ -24,6 +24,10 @@ segmenter shard per device — and maps short-lived streams onto slots:
 Wire framing is per-stream and stream-local (position 0 = the stream's
 first point), so slot placement and tick phasing leave no trace in the
 bytes.
+
+``admit``, ``evict``, each ε-plane upload and the three phases of
+``step`` (dispatch, fetch, emit: one span per shard, none per slot) open
+``jax.profiler.TraceAnnotation`` spans named ``repro.slots.*``.
 """
 
 from __future__ import annotations
@@ -34,6 +38,7 @@ from typing import Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation as span
 
 from repro.core import jax_pla
 from repro.core.evaluate import METHOD_KNOT_KINDS
@@ -151,6 +156,10 @@ class SlotManager:
 
     def admit(self, stream_id: str, eps: Optional[float] = None) -> Slot:
         """Bind ``stream_id`` to a free slot (LIFO — slots recycle hot)."""
+        with span("repro.slots.admit"):
+            return self._admit(stream_id, eps)
+
+    def _admit(self, stream_id: str, eps: Optional[float]) -> Slot:
         if stream_id in self._by_stream:
             raise ValueError(f"stream {stream_id!r} is already admitted")
         if not self._free:
@@ -189,6 +198,10 @@ class SlotManager:
 
     def evict(self, stream_id: str) -> EvictReport:
         """Close the stream: flush its carry row and drain its emitter."""
+        with span("repro.slots.evict"):
+            return self._evict(stream_id)
+
+    def _evict(self, stream_id: str) -> EvictReport:
         i = self._by_stream.pop(stream_id, None)
         if i is None:
             raise KeyError(f"stream {stream_id!r} is not admitted")
@@ -255,10 +268,11 @@ class SlotManager:
 
     def _push_shard_eps(self, d: int) -> None:
         lo = d * self.rows_per_shard
-        row = jax.device_put(
-            jnp.asarray(self._eps[lo:lo + self.rows_per_shard]),
-            self.devices[d])
-        self._states[d] = jax_pla.masked_set_eps(self._states[d], row)
+        with span("repro.slots.set_eps"):
+            row = jax.device_put(
+                jnp.asarray(self._eps[lo:lo + self.rows_per_shard]),
+                self.devices[d])
+            self._states[d] = jax_pla.masked_set_eps(self._states[d], row)
 
     # -- tick stepping -------------------------------------------------------
 
@@ -285,35 +299,39 @@ class SlotManager:
             rows = slice(d * R, (d + 1) * R)
             if lengths[rows].max(initial=0) == 0:
                 continue
-            shard_y = jax.device_put(jnp.asarray(plane[rows]), dev)
-            self._states[d], outs[d] = jax_pla.masked_step_chunk(
-                self._states[d], shard_y, lengths[rows])
+            with span("repro.slots.dispatch"):
+                shard_y = jax.device_put(jnp.asarray(plane[rows]), dev)
+                self._states[d], outs[d] = jax_pla.masked_step_chunk(
+                    self._states[d], shard_y, lengths[rows])
         wire: List[Tuple[str, int, bytes]] = []
         for d, out in outs.items():
-            ev = np.asarray(out.ev)
-            pos = np.asarray(out.pos)
-            a = np.asarray(out.a)
-            v = np.asarray(out.v)
-            for r in range(R):
-                i = d * R + r
-                c = int(lengths[i])
-                if c == 0:
-                    continue
-                slot = self.slots[i]
-                js = np.flatnonzero(ev[r])
-                part = self._feed_slot(slot, pos[r:r + 1, js],
-                                       a[r:r + 1, js], v[r:r + 1, js],
-                                       np.ones((1, js.size), bool),
-                                       plane[i, :c][None])
-                slot.points += c
-                self.total_points += c
-                blob = self._blob(part)
-                if blob:
-                    if self.store is not None:
-                        self._archive(slot, [part])
-                    slot.nbytes += len(blob)
-                    self.total_bytes += len(blob)
-                    wire.append((slot.stream_id, slot.generation, blob))
+            # Where the host waits for the shard's step.
+            with span("repro.slots.fetch"):
+                ev = np.asarray(out.ev)
+                pos = np.asarray(out.pos)
+                a = np.asarray(out.a)
+                v = np.asarray(out.v)
+            with span("repro.slots.emit"):
+                for r in range(R):
+                    i = d * R + r
+                    c = int(lengths[i])
+                    if c == 0:
+                        continue
+                    slot = self.slots[i]
+                    js = np.flatnonzero(ev[r])
+                    part = self._feed_slot(slot, pos[r:r + 1, js],
+                                           a[r:r + 1, js], v[r:r + 1, js],
+                                           np.ones((1, js.size), bool),
+                                           plane[i, :c][None])
+                    slot.points += c
+                    self.total_points += c
+                    blob = self._blob(part)
+                    if blob:
+                        if self.store is not None:
+                            self._archive(slot, [part])
+                        slot.nbytes += len(blob)
+                        self.total_bytes += len(blob)
+                        wire.append((slot.stream_id, slot.generation, blob))
         return wire
 
     def _feed_slot(self, slot: Slot, pos, a, v, ev, values):

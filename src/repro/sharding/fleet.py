@@ -48,6 +48,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax.profiler import TraceAnnotation as span
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.compat import sharding as cs
@@ -347,23 +348,34 @@ class FleetStream:
         if y.ndim != 2 or y.shape[0] != self.n_streams:
             raise ValueError(f"chunk must be ({self.n_streams}, n); "
                              f"got {y.shape}")
-        # Dispatch every shard's segmenter launch before packing any of
-        # them: the host-side packer blocks on its shard's device, so a
-        # fused loop would serialize the devices.
-        shard_events = []
-        for d, seg in enumerate(self._segs):
-            rows = y[d * self._rows:(d + 1) * self._rows]
-            shard = jax.device_put(rows, self.devices[d])
-            shard_events.append((rows, seg.push(shard)))
-        out: List = []
-        for d, (em, (rows, events)) in enumerate(zip(self._ems,
-                                                     shard_events)):
-            blobs = em.step_chunk(events, rows)
-            self._account(d, blobs)
-            out.extend(blobs)
-        self.pushed += y.shape[1]
-        if self.store is not None:
-            self.store.append(out)
+        with span("repro.fleet.push", streams=self.n_streams,
+                  width=y.shape[1]):
+            # Dispatch every shard's segmenter launch before packing any
+            # of them: the fetch blocks on its shard's device, so a fused
+            # loop would serialize the devices.
+            shard_events = []
+            for d, seg in enumerate(self._segs):
+                rows = y[d * self._rows:(d + 1) * self._rows]
+                with span("repro.fleet.put"):
+                    shard = jax.device_put(rows, self.devices[d])
+                with span("repro.fleet.segment"):
+                    shard_events.append((rows, seg.push(shard)))
+            out: List = []
+            for d, (em, (rows, events)) in enumerate(zip(self._ems,
+                                                         shard_events)):
+                # The host's wait for the kernel and the copy of the
+                # event planes, which the packer would otherwise make.
+                with span("repro.fleet.fetch",
+                          bytes=sum(x.nbytes for x in events)):
+                    events = jax.device_get(events)
+                with span("repro.fleet.emit"):
+                    blobs = em.step_chunk(events, rows)
+                self._account(d, blobs)
+                out.extend(blobs)
+            self.pushed += y.shape[1]
+            if self.store is not None:
+                with span("repro.fleet.store"):
+                    self.store.append(out)
         return out
 
     def finish(self) -> List:
@@ -371,18 +383,19 @@ class FleetStream:
         if self._finished:
             raise RuntimeError("finish() called twice")
         self._finished = True
-        finals = [seg.finish() for seg in self._segs]
-        out: List = []
-        for d, (em, events) in enumerate(zip(self._ems, finals)):
-            blobs = em.step_chunk(events)
-            tails = em.flush()
-            self._account(d, blobs)
-            self._account(d, tails)
-            if self.protocol == "twostreams":
-                out.extend((a + c, b + e)
-                           for (a, b), (c, e) in zip(blobs, tails))
-            else:
-                out.extend(b + t for b, t in zip(blobs, tails))
-        if self.store is not None:
-            self.store.append(out, close=True)
+        with span("repro.fleet.finish"):
+            finals = [seg.finish() for seg in self._segs]
+            out: List = []
+            for d, (em, events) in enumerate(zip(self._ems, finals)):
+                blobs = em.step_chunk(events)
+                tails = em.flush()
+                self._account(d, blobs)
+                self._account(d, tails)
+                if self.protocol == "twostreams":
+                    out.extend((a + c, b + e)
+                               for (a, b), (c, e) in zip(blobs, tails))
+                else:
+                    out.extend(b + t for b, t in zip(blobs, tails))
+            if self.store is not None:
+                self.store.append(out, close=True)
         return out
