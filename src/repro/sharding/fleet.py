@@ -29,8 +29,9 @@ Layers:
   vectorized host packer (:func:`~repro.core.protocol_engine.encode_batch`);
 - :class:`FleetStream` — the chunked face: per-device
   :class:`~repro.kernels.ops.StreamingSegmenter` carries and
-  :class:`~repro.core.protocol_engine.ProtocolEmitter` codec state, so a
-  live fleet can push ``(S, n)`` column batches and receive wire-ready
+  :class:`~repro.core.protocol_engine.ProtocolEmitter` codec state (one
+  emitter per row block of a shard, the blocks packed concurrently), so
+  a live fleet can push ``(S, n)`` column batches and receive wire-ready
   bytes per stream, bit-identical to the offline encode of the whole
   stream (PR-2 carry contract per shard).
 
@@ -42,6 +43,8 @@ from __future__ import annotations
 
 import dataclasses
 import functools
+import os
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional
 
 import jax
@@ -69,6 +72,10 @@ __all__ = ["FLEET_AXIS", "FleetPointMetrics", "FleetStream",
            "fleet_encode", "pad_to_mesh"]
 
 FLEET_AXIS = "streams"
+
+# Fewest rows a shard's packer gives one worker thread: a block of 1,024
+# streams keeps the emitter's per-call Python a few percent of its numpy.
+MIN_BLOCK_ROWS = 1024
 
 
 def fleet_mesh(n_devices: Optional[int] = None, *,
@@ -256,8 +263,26 @@ def fleet_encode(fm: FleetPointMetrics, y, *, t0: float = 0.0,
 
 
 # ---------------------------------------------------------------------------
-# Chunked fleet ingest: per-device carries + per-device codec state
+# Chunked fleet ingest: per-device carries + per-row-block codec state
 # ---------------------------------------------------------------------------
+
+def _usable_cpus() -> int:
+    """CPUs this process may run on (its affinity mask where the OS has
+    one)."""
+    try:
+        return len(os.sched_getaffinity(0))
+    except AttributeError:
+        return os.cpu_count() or 1
+
+
+def _row_blocks(rows: int) -> List[slice]:
+    """Contiguous row blocks of a shard, one per packer thread: as many
+    as there are usable CPUs, but none under ``MIN_BLOCK_ROWS`` rows
+    (a single block below that)."""
+    k = max(1, min(_usable_cpus(), rows // MIN_BLOCK_ROWS))
+    edges = [rows * i // k for i in range(k + 1)]
+    return [slice(a, b) for a, b in zip(edges, edges[1:])]
+
 
 class FleetStream:
     """Live fleet ingest: push ``(S, n)`` column batches, get wire bytes.
@@ -266,8 +291,13 @@ class FleetStream:
     each shard owns a :class:`~repro.kernels.ops.StreamingSegmenter`
     (kernel carry state pinned to that device via ``jax.device_put`` of
     its chunks) and a host
-    :class:`~repro.core.protocol_engine.ProtocolEmitter`, whose float64
-    codec arithmetic is the legacy codecs' own on every platform.
+    :class:`~repro.core.protocol_engine.ProtocolEmitter` per row block,
+    whose float64 codec arithmetic is the legacy codecs' own on every
+    platform.  A stream's codec state depends on its own row alone, so
+    the blocks of a shard pack concurrently on a thread pool (the numpy
+    work releases the GIL) and give exactly the bytes one emitter over
+    the shard would; the block count follows the usable CPUs and the
+    rows per shard (:func:`_row_blocks`).
     ``push`` fans the chunk out shard-by-shard and returns the newly
     wire-ready bytes per stream — for the deferred methods
     (continuous/mixed) a shard's emission lags its released columns,
@@ -309,10 +339,14 @@ class FleetStream:
                                          max_run=max_run, window=window,
                                          **segmenter_kw)
                       for _ in range(d)]
-        self._ems = [ProtocolEmitter(protocol, self._rows,
-                                     knot_kind=self.knot_kind, t0=t0, dt=dt,
-                                     burst_cap=burst_cap)
+        self._blocks = _row_blocks(self._rows)
+        self._ems = [[ProtocolEmitter(protocol, b.stop - b.start,
+                                      knot_kind=self.knot_kind, t0=t0,
+                                      dt=dt, burst_cap=burst_cap)
+                      for b in self._blocks]
                      for _ in range(d)]
+        self._pool = ThreadPoolExecutor(len(self._blocks)) \
+            if len(self._blocks) > 1 else None
         self.shard_bytes = np.zeros(d, np.int64)
         self.pushed = 0
         self._finished = False
@@ -340,6 +374,25 @@ class FleetStream:
         else:
             self.shard_bytes[d] += sum(len(b) for b in blobs)
 
+    def _emit(self, ems, events, rows=None, close=False):
+        """Step each row block's emitter on its rows of the host
+        ``events`` and values ``rows`` (and flush it, with ``close``),
+        concurrently where there are several blocks.  Returns the
+        shard's stepped bytes and flush tails, each in row order."""
+        def step(em, b):
+            with span("repro.fleet.emit_block", rows=b.stop - b.start):
+                blobs = em.step_chunk(
+                    SegmentOutput(*(x[b] for x in events)),
+                    None if rows is None else rows[b])
+                return blobs, em.flush() if close else []
+
+        if self._pool is None:
+            parts = [step(ems[0], self._blocks[0])]
+        else:
+            parts = list(self._pool.map(step, ems, self._blocks))
+        return ([blob for blobs, _ in parts for blob in blobs],
+                [tail for _, tails in parts for tail in tails])
+
     def push(self, y_chunk) -> List:
         """Feed ``(S, n)`` columns; returns the new bytes per stream."""
         if self._finished:
@@ -361,15 +414,15 @@ class FleetStream:
                 with span("repro.fleet.segment"):
                     shard_events.append((rows, seg.push(shard)))
             out: List = []
-            for d, (em, (rows, events)) in enumerate(zip(self._ems,
-                                                         shard_events)):
+            for d, (ems, (rows, events)) in enumerate(zip(self._ems,
+                                                          shard_events)):
                 # The host's wait for the kernel and the copy of the
                 # event planes, which the packer would otherwise make.
                 with span("repro.fleet.fetch",
                           bytes=sum(x.nbytes for x in events)):
                     events = jax.device_get(events)
-                with span("repro.fleet.emit"):
-                    blobs = em.step_chunk(events, rows)
+                with span("repro.fleet.emit", blocks=len(self._blocks)):
+                    blobs, _ = self._emit(ems, events, rows)
                 self._account(d, blobs)
                 out.extend(blobs)
             self.pushed += y.shape[1]
@@ -386,9 +439,9 @@ class FleetStream:
         with span("repro.fleet.finish"):
             finals = [seg.finish() for seg in self._segs]
             out: List = []
-            for d, (em, events) in enumerate(zip(self._ems, finals)):
-                blobs = em.step_chunk(events)
-                tails = em.flush()
+            for d, (ems, events) in enumerate(zip(self._ems, finals)):
+                blobs, tails = self._emit(ems, jax.device_get(events),
+                                          close=True)
                 self._account(d, blobs)
                 self._account(d, tails)
                 if self.protocol == "twostreams":
@@ -398,4 +451,6 @@ class FleetStream:
                     out.extend(b + t for b, t in zip(blobs, tails))
             if self.store is not None:
                 self.store.append(out, close=True)
+        if self._pool is not None:
+            self._pool.shutdown()
         return out

@@ -85,6 +85,103 @@ def test_fleet_stream_chunked_bit_identical():
         assert fs.total_bytes == sum(len(b) for b in got)
 
 
+# ---------------------------------------------------------------------------
+# Row-block packing: several emitters per shard give the one emitter's bytes
+# ---------------------------------------------------------------------------
+
+def _fleet_bytes(fs, y, widths):
+    """Every stream's bytes over pushes of ``widths`` and the finish."""
+    outs, pos = [], 0
+    for w in widths:
+        outs.append(fs.push(y[:, pos:pos + w]))
+        pos += w
+    outs.append(fs.finish())
+    if fs.protocol == "twostreams":
+        return [(b"".join(a for a, _ in parts), b"".join(b for _, b in parts))
+                for parts in zip(*outs)]
+    return [b"".join(parts) for parts in zip(*outs)]
+
+
+@pytest.mark.parametrize("method,protocol,S,min_rows,blocks", [
+    ("linear", "singlestream", 16, 8, 2),
+    ("angle", "singlestreamv", 24, 8, 3),
+    ("continuous", "implicit", 32, 8, 4),
+    ("linear", "twostreams", 30, 10, 3),
+])
+def test_fleet_row_blocks_bytes_equal_one_block(monkeypatch, method,
+                                                protocol, S, min_rows,
+                                                blocks):
+    from repro.sharding import fleet as fleet_mod
+    from repro.store import SegmentStore
+
+    eps, widths = 0.8, (37, 64, 5, 90)
+    y = _batch(seed=7, S=S, T=sum(widths))
+    kw = dict(block_s=8, block_t=32)
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(blocks)), raising=False)
+    one_store = SegmentStore(protocol, eps=eps)
+    one = FleetStream(method, protocol, S, eps, store=one_store, **kw)
+    monkeypatch.setattr(fleet_mod, "MIN_BLOCK_ROWS", min_rows)
+    store = SegmentStore(protocol, eps=eps)
+    fs = FleetStream(method, protocol, S, eps, store=store, **kw)
+    assert len(one._blocks) == 1 and one._pool is None
+    assert len(fs._blocks) == blocks
+    got = _fleet_bytes(fs, y, widths)
+    assert got == _fleet_bytes(one, y, widths)
+    cap = PROTOCOL_CAPS[protocol] or 256
+    kk = METHOD_KNOT_KINDS.get(method, "disjoint")
+    off = encode_batch(BATCHED_SEGMENTERS[method](y, eps, max_run=cap), y,
+                       protocol, kk)
+    assert got == [tuple(b) if protocol == "twostreams" else b
+                   for b in off]
+    assert fs.total_bytes == one.total_bytes == sum(
+        len(b"".join(b)) if protocol == "twostreams" else len(b)
+        for b in got)
+    assert store.keys() == one_store.keys()
+    for k in store.keys():
+        mine, ref = store._streams[k], one_store._streams[k]
+        assert bytes(mine.payload) == bytes(ref.payload)
+        assert bytes(mine.payload2) == bytes(ref.payload2)
+        assert mine.e_pos == ref.e_pos
+        np.testing.assert_array_equal(store.scan([k])[k],
+                                      one_store.scan([k])[k])
+
+
+@pytest.mark.parametrize("cpus,rows", [
+    (1, 40960), (13, 40960), (64, 40960), (8, 1023), (8, 2048),
+    (24, 10240), (4, 5000),
+])
+def test_fleet_row_block_count(monkeypatch, cpus, rows):
+    """The block count follows the usable CPUs and the rows per shard,
+    and the blocks tile the shard's rows in order."""
+    from repro.sharding import fleet as fleet_mod
+    from repro.sharding.fleet import MIN_BLOCK_ROWS, _row_blocks
+
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(cpus)), raising=False)
+    blocks = _row_blocks(rows)
+    k = len(blocks)
+    assert k == max(1, min(cpus, rows // MIN_BLOCK_ROWS))
+    assert k <= cpus and (k == 1 or k <= rows // MIN_BLOCK_ROWS)
+    assert blocks[0].start == 0 and blocks[-1].stop == rows
+    assert all(a.stop == b.start for a, b in zip(blocks, blocks[1:]))
+    assert k == 1 or min(b.stop - b.start for b in blocks) \
+        >= MIN_BLOCK_ROWS
+    # Without an affinity mask the CPU count stands in for it.
+    monkeypatch.delattr(os, "sched_getaffinity", raising=False)
+    monkeypatch.setattr(os, "cpu_count", lambda: cpus)
+    assert len(fleet_mod._row_blocks(rows)) == k
+
+
+def test_fleet_small_shard_packs_in_one_block(monkeypatch):
+    monkeypatch.setattr(os, "sched_getaffinity",
+                        lambda pid: set(range(64)), raising=False)
+    fs = FleetStream("linear", "singlestream", 16, 1.0)
+    assert [(b.start, b.stop) for b in fs._blocks] == [(0, 16)]
+    assert fs._pool is None
+    assert all(len(ems) == 1 for ems in fs._ems)
+
+
 def test_fleet_shape_and_mesh_errors():
     y = _batch(S=8)
     with pytest.raises(ValueError, match="unknown protocol"):

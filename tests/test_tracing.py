@@ -52,9 +52,12 @@ def _inside(spans, outer):
         oa <= a and b <= ob for m, oa, ob, _ in spans if m == outer))
 
 
-def test_fleet_push_spans(tmp_path):
+def test_fleet_push_spans(tmp_path, monkeypatch):
+    from repro.sharding import fleet as fleet_mod
     S, W = 16, 64
     y = _walk(S, 3 * W)
+    # Row blocks of 4 streams, so the packer runs on its thread pool.
+    monkeypatch.setattr(fleet_mod, "MIN_BLOCK_ROWS", 4)
     fs = FleetStream("linear", "singlestream", S, 0.8, block_s=8,
                      block_t=32)
     fs.push(y[:, :W])           # compiles outside the trace
@@ -83,6 +86,18 @@ def test_fleet_push_spans(tmp_path):
     # stream and released column.
     fetched = [s[3]["bytes"] for s in spans if s[0] == "repro.fleet.fetch"]
     assert sum(fetched) == S * 2 * W * (1 + 4 + 4)
+    # The emitter span names its row blocks; each block's worker opens
+    # one span inside it, on whichever thread ran it.
+    blocks = len(fs._blocks)
+    emits = [s for s in spans if s[0] == "repro.fleet.emit"]
+    assert emits and all(s[3] == {"blocks": blocks} for s in emits)
+    for _, a, b, _ in pushes:
+        assert sum(1 for n, c, d, _ in spans if n == "repro.fleet.emit_block"
+                   and a <= c and d <= b) == blocks * fs.n_devices
+    assert sum(s[3]["rows"] for s in spans
+               if s[0] == "repro.fleet.emit_block"
+               and pushes[0][1] <= s[1] and s[2] <= pushes[0][2]) == S
+    assert inside["repro.fleet.emit_block"] == 2 * blocks * fs.n_devices
 
 
 def test_fleet_store_span(tmp_path):
