@@ -17,8 +17,12 @@ SAMPLE_BYTES = 4
 EVENT_BYTES = 1 + 4 + 4
 
 
-def segmenter_bytes(n_streams: int, n_steps: int) -> int:
-    s_pad = -(-n_streams // LANES) * LANES
+def segmenter_bytes(n_streams: int, n_steps: int, chips: int = 1) -> int:
+    """The bytes one chip's launch moves over ``n_steps`` steps, where
+    ``n_streams`` streams are split row-wise over ``chips`` chips: its
+    shard of ``n_streams // chips`` rows, padded to whole 128-lane
+    blocks."""
+    s_pad = -(-(n_streams // chips) // LANES) * LANES
     return s_pad * n_steps * (SAMPLE_BYTES + EVENT_BYTES)
 
 

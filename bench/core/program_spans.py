@@ -8,52 +8,27 @@ lie on the profiler's host plane, on the device planes' clock, so they
 can name what the host was doing in each gap of the device.
 
 A span here is ``(name, start, end, args)``: seconds on the trace's
-clock and a dict of the span's arguments.  ``bench/core/trace.py`` keeps
-only the benchmark's own ``bench.*`` spans; ``load_program_spans`` reads
-the ``repro.*`` ones from the same ``.xplane.pb``.
+clock and a dict of the span's arguments.  ``bench/core/trace.py:
+load_trace`` keeps the window's as ``Trace.program_spans``, and
+``Trace.idle_gaps`` names each idle gap of the device by the innermost
+span of either family open in it.
 """
 
 from __future__ import annotations
 
 import bisect
-import glob
-import os
-from typing import Dict, List, Optional, Sequence, Tuple
+from typing import List, Optional, Sequence, Tuple
 
-from bench.core.trace import _union
-
-PREFIX = "repro."
-OUTSIDE = "outside bench spans"
-TOP = 10
+from bench.core.trace import read_profile, _union
 
 Span = Tuple[str, float, float, dict]
 
 
-def load_program_spans(trace_dir: str,
-                       window: Optional[Tuple[float, float]] = None
-                       ) -> List[Span]:
+def load_program_spans(trace_dir: str) -> List[Span]:
     """The ``repro.*`` host spans of the newest ``.xplane.pb`` under
-    ``trace_dir``, in order of their start; those that overlap ``window``
-    where one is given."""
-    from jax.profiler import ProfileData
-    paths = glob.glob(os.path.join(trace_dir, "**", "*.xplane.pb"),
-                      recursive=True)
-    if not paths:
-        raise FileNotFoundError(f"no .xplane.pb under {trace_dir}")
-    pd = ProfileData.from_file(max(paths, key=os.path.getmtime))
-    spans = []
-    for plane in pd.planes:
-        if not plane.name.startswith("/host:"):
-            continue
-        for line in plane.lines:
-            for ev in line.events:
-                if ev.name.startswith(PREFIX):
-                    a = ev.start_ns * 1e-9
-                    spans.append((ev.name, a, a + ev.duration_ns * 1e-9,
-                                  dict(ev.stats)))
-    if window is not None:
-        spans = [s for s in spans if s[2] > window[0] and s[1] < window[1]]
-    return sorted(spans, key=lambda s: (s[1], -s[2]))
+    ``trace_dir``, in order of their start; the trace need hold no
+    window."""
+    return read_profile(trace_dir)[3]
 
 
 def _named(spans: Sequence[Span], name: str) -> List[Span]:
@@ -107,48 +82,3 @@ def covered_share(spans: Sequence[Span], parent: str,
             covered += max(0.0, min(b, kids[i][1]) - max(a, kids[i][0]))
             i += 1
     return covered / total if total else None
-
-
-def _innermost(spans: Sequence[Span]):
-    """A function from a time to the name of the innermost span open at
-    it.  Spans of one thread nest, so the innermost span open at ``t`` is
-    the span that started last before ``t`` or one of its ancestors."""
-    order = sorted(spans, key=lambda s: (s[1], -s[2]))
-    starts = [s[1] for s in order]
-    parent: List[int] = []
-    stack: List[int] = []
-    for k, (_, a, _, _) in enumerate(order):
-        while stack and order[stack[-1]][2] <= a:
-            stack.pop()
-        parent.append(stack[-1] if stack else -1)
-        stack.append(k)
-
-    def at(t: float) -> str:
-        k = bisect.bisect_right(starts, t) - 1
-        while k >= 0 and order[k][2] <= t:
-            k = parent[k]
-        return order[k][0] if k >= 0 else OUTSIDE
-
-    return at
-
-
-def idle_gaps(trace, spans: Sequence[Span]) -> List[list]:
-    """The idle time of the first chip by what the host was doing: as
-    ``Trace.breakdown``'s ``idle_gaps``, but each gap goes to the
-    innermost span open at its middle of either family, a program span
-    or the benchmark's own, so that a ``bench.*`` name is left only
-    where no program span was open."""
-    if not trace.ops:
-        return []
-    at = _innermost([(n, a, b, {}) for n, a, b in trace.spans]
-                    + list(spans))
-    busy = _union([(o.start, o.end) for o in trace.ops[0]])
-    gaps: Dict[str, float] = {}
-    t = trace.window[0]
-    for a, b in busy + [(trace.window[1], trace.window[1])]:
-        if a > t:
-            who = at(0.5 * (t + a))
-            gaps[who] = gaps.get(who, 0.0) + a - t
-        t = max(t, b)
-    return sorted(([k, v] for k, v in gaps.items()),
-                  key=lambda kv: -kv[1])[:TOP]

@@ -1,6 +1,6 @@
 """Share of the traced serving window in which no operation ran on the
-chip: 100 * (1 - busy / window), busy being the union of the device
-operations' intervals."""
+chip: 100 * (1 - busy / window), busy being the union of a chip's
+device operations' intervals, as a mean over the chips used."""
 
 
 def read(run):

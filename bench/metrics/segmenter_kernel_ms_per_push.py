@@ -1,5 +1,7 @@
-"""Device time of the fleet's Pallas segmenter kernel per push: the summed
-durations of its operations in the traced window over the pushes made."""
+"""Device time of the fleet's Pallas segmenter kernel per push: the union
+of its operations' intervals in the traced window on each chip, as a
+mean over the chips (each runs its own shard's launch), over the pushes
+made."""
 
 from bench.core.kernels import is_segmenter
 

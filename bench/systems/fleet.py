@@ -11,7 +11,8 @@ N(0, 1) walk is such a walk too, and each turn repeats one sample.  The
 window pushes until ``seconds`` have passed, and records each push's
 start, end and points.  After the window the fleet is finished and each
 sampled stream's bytes, from its first push to its close, are its
-answer.
+answer: ``SAMPLE_STREAMS`` streams drawn from the seed, an equal number
+from each chip's shard.
 """
 
 from __future__ import annotations
@@ -46,10 +47,16 @@ class System:
         self.eps = float(c["eps"])
         self.method, self.protocol = tr["method"], tr["protocol"]
         self.t0, self.dt = float(c["t0"]), float(c["dt"])
+        # The fleet splits its rows into one contiguous shard per chip;
+        # each shard gives SAMPLE_STREAMS // chips sampled rows, so that a
+        # fault confined to one shard shows.  On one chip this is one draw
+        # over all rows.
         rng = np.random.default_rng(self.seed)
-        n_sample = min(SAMPLE_STREAMS, self.n_streams)
-        self.rows = np.sort(rng.choice(self.n_streams, n_sample,
-                                       replace=False))
+        per = self.n_streams // len(devices)
+        k = min(SAMPLE_STREAMS // len(devices), per)
+        self.rows = np.concatenate([
+            d * per + np.sort(rng.choice(per, k, replace=False))
+            for d in range(len(devices))])
         self.order = []             # index into blocks of each push
         self.kept = [[] for _ in self.rows]
 

@@ -1,15 +1,29 @@
 """A whole run of one cell on the CPU at a tiny size, optionally with the
 timed path broken underneath (for the tests; never a measurement).
 
-    python -m bench.tests.cpu_run <workload> <seed> <seconds> [fault]
+    python -m bench.tests.cpu_run <workload> <seed> <seconds> [fault] \
+        [--chips N]
+
+The run gets as many CPU devices as the cell has chips: the count is
+added to ``XLA_FLAGS`` (``--xla_force_host_platform_device_count``)
+before JAX starts.  ``--chips N`` runs an existing cell at another chip
+count, the fleet split over N devices; it is for these tests alone, and
+nothing on the chip path reads it.
 
 Faults: ``state_unchanged`` (the segmenter's step hands back the state
 it was given), ``half_dropped`` (half of the streams' answers are left
 out), ``answer_altered`` (each emitted blob has a byte changed where the
-emitter produces it).  There is one chip, so no exchange between chips
-to leave out.
+emitter produces it), and, where a cell runs on more than one chip,
+``shard_misplaced`` (``FleetStream.push`` hands back its shards' outputs
+rotated by one shard, so shard d's rows carry shard d+1's bytes) and
+``shard_altered`` (as ``answer_altered``, but in the last shard's rows
+alone, which the check sees only where it samples every shard).  The
+shards of a fleet exchange nothing, so no fault leaves out an exchange
+between chips.
 """
 
+import argparse
+import os
 import sys
 
 TINY = {           # config, traffic, the system module's constants
@@ -22,7 +36,11 @@ TINY = {           # config, traffic, the system module's constants
 }
 
 
-def shrink(cell) -> None:
+def shrink(cell, chips=None) -> None:
+    """Cut the cell to the tiny size; ``chips`` overrides its chip
+    count."""
+    if chips:
+        cell.chips = chips
     conf, traffic, consts = TINY[cell.config["system"]]
     cell.config.update(conf)
     cell.traffic.update(traffic)
@@ -70,6 +88,24 @@ def plant(fault: str) -> None:
         sstep = SlotManager.step
         SlotManager.step = lambda self, plane, lengths: [
             w for w in sstep(self, plane, lengths) if int(w[0]) % 2 == 0]
+    elif fault == "shard_misplaced":
+        fpush = FleetStream.push
+
+        def push_rotated(self, y):
+            out = fpush(self, y)
+            rows = len(out) // self.n_devices
+            return out[rows:] + out[:rows]
+
+        FleetStream.push = push_rotated
+    elif fault == "shard_altered":
+        fpush = FleetStream.push
+
+        def push_altering_last(self, y):
+            out = fpush(self, y)
+            first = len(out) - len(out) // self.n_devices
+            return out[:first] + [_alter(b) for b in out[first:]]
+
+        FleetStream.push = push_altering_last
     elif fault == "answer_altered":
         emit = ProtocolEmitter.step_chunk
         ProtocolEmitter.step_chunk = \
@@ -79,12 +115,24 @@ def plant(fault: str) -> None:
 
 
 def main(argv) -> int:
+    ap = argparse.ArgumentParser(prog="python -m bench.tests.cpu_run")
+    ap.add_argument("workload")
+    ap.add_argument("seed")
+    ap.add_argument("seconds")
+    ap.add_argument("fault", nargs="?")
+    ap.add_argument("--chips", type=int)
+    args = ap.parse_args(argv)
+    from bench.core.cell import load_cell
+    chips = args.chips or load_cell(args.workload).chips
+    flags = os.environ.get("XLA_FLAGS", "")
+    os.environ["XLA_FLAGS"] = \
+        f"{flags} --xla_force_host_platform_device_count={chips}".strip()
+    if args.fault:
+        plant(args.fault)
     from bench.core.harness import main as run
-    workload, seed, seconds = argv[:3]
-    if len(argv) > 3:
-        plant(argv[3])
-    return run(["--workload", workload, "--seed", seed, "--seconds", seconds,
-                "--trace", "0"], require_chip=False, adjust=shrink)
+    return run(["--workload", args.workload, "--seed", args.seed,
+                "--seconds", args.seconds, "--trace", "0"],
+               require_chip=False, adjust=lambda c: shrink(c, args.chips))
 
 
 if __name__ == "__main__":

@@ -60,3 +60,18 @@ def test_benchmark_stands_without_the_pending_cells():
         assert set(m.get("workloads", cells)) <= cells, m["name"]
     assert cells.isdisjoint(
         w["name"] for w in BENCH["workloads"][len(bench["workloads"]):])
+
+
+def test_chip_counts():
+    """Every cell asks for 1 or 4 chips, and at most half the benchmark's
+    cells (rounded down, but always one) ask for four."""
+    assert {w["chips"] for w in BENCH["workloads"]} <= {1, 4}
+    cells = json.loads((ROOT / "BENCHMARK.json").read_text())["workloads"]
+    four = [w["name"] for w in cells if w["chips"] == 4]
+    assert len(four) <= max(1, len(cells) // 2), four
+
+
+def test_metric_workloads_name_cells():
+    cells = {w["name"] for w in BENCH["workloads"]}
+    for m in BENCH["end_to_end"] + BENCH["per_layer"]:
+        assert set(m.get("workloads", ())) <= cells, m["name"]

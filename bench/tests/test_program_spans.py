@@ -5,7 +5,7 @@ import pytest
 
 from bench.core import program_spans as ps
 from bench.core.cell import BENCH_DIR, load_module
-from bench.core.trace import Op, Trace
+from bench.core.trace import OUTSIDE, Op, Trace
 from bench.tests.test_trace import _Run, _trace
 
 OLD_READERS = ("device_idle_share.fleet", "segmenter_kernel_ms_per_push",
@@ -107,32 +107,39 @@ def test_covered_share():
 
 
 def test_idle_gaps_go_to_the_innermost_program_span():
-    tr = _trace()
-    gaps = dict(ps.idle_gaps(tr, FLEET))
-    # [0, 0.1] in the put, [0.3, 0.5] in the emit (its middle, 0.4); the
-    # middle of [0.6, 0.9] lies after the program's tick closed, so that
-    # gap keeps the benchmark's span.
-    assert gaps == pytest.approx({"repro.fleet.put": 0.1,
-                                  "repro.fleet.emit": 0.2,
-                                  "bench.serve.tick": 0.3})
-    # With no program span the gaps are Trace.breakdown's own.
-    assert dict(ps.idle_gaps(tr, [])) == \
-        pytest.approx(dict(tr.breakdown()["idle_gaps"]))
+    tr = _with_spans(_trace(), FLEET)
+    gaps = dict(tr.idle_gaps())
+    # [0, 0.1] in the put and the segment; [0.3, 0.5] in the emit, the
+    # two benchmark spans and the program's tick; [0.6, 0.9] in that
+    # tick, then the benchmark's, then none.
+    assert gaps == pytest.approx({"repro.fleet.put": 0.08,
+                                  "repro.fleet.segment": 0.02,
+                                  "repro.fleet.emit": 0.14,
+                                  "bench.fleet.push": 0.01,
+                                  "bench.serve.tick": 0.11,
+                                  "repro.serve.tick": 0.14,
+                                  OUTSIDE: 0.1})
+    assert dict(tr.breakdown()["idle_gaps"]) == gaps
+    # With no program span the gaps are the benchmark's spans' alone.
+    assert dict(_with_spans(tr, []).idle_gaps()) == \
+        pytest.approx(dict(_trace().breakdown()["idle_gaps"]))
 
 
 def test_idle_gaps_of_either_family_and_none():
     tr = Trace((0.0, 1.0), [[Op("fusion", 0.4, 0.6)]],
                [("bench.serve.tick", 0.0, 0.3)])
-    # [0, 0.4] has its middle in the benchmark's tick, [0.6, 1.0] in the
-    # program's.
-    assert dict(ps.idle_gaps(tr, [("repro.serve.tick", 0.7, 1.0, {})])) \
-        == pytest.approx({"bench.serve.tick": 0.4, "repro.serve.tick": 0.4})
-    # The middle of [0, 0.9] lies in no span.
+    # [0, 0.3] in the benchmark's tick, [0.7, 1.0] in the program's, the
+    # rest of the idle time in none.
+    tr.program_spans = [("repro.serve.tick", 0.7, 1.0, {})]
+    assert dict(tr.idle_gaps()) == pytest.approx(
+        {"bench.serve.tick": 0.3, "repro.serve.tick": 0.3, OUTSIDE: 0.2})
+    # Spans that open together: the shorter is the inner one.
     tr = Trace((0.0, 1.0), [[Op("fusion", 0.9, 1.0)]],
                [("bench.serve.tick", 0.0, 0.2)])
-    assert dict(ps.idle_gaps(tr, [("repro.serve.tick", 0.0, 0.1, {})])) \
-        == pytest.approx({ps.OUTSIDE: 0.9})
-    assert ps.idle_gaps(Trace((0.0, 1.0), [], []), []) == []
+    tr.program_spans = [("repro.serve.tick", 0.0, 0.1, {})]
+    assert dict(tr.idle_gaps()) == pytest.approx(
+        {"repro.serve.tick": 0.1, "bench.serve.tick": 0.1, OUTSIDE: 0.7})
+    assert Trace((0.0, 1.0), [], []).idle_gaps() == []
 
 
 @pytest.mark.parametrize("name", OLD_READERS)
@@ -197,6 +204,7 @@ def test_spans_tool_report():
         pytest.approx(100.0)
     assert set(PROGRAM_METRICS) | {m["name"] for m in cell.per_layer} == \
         set(line["metrics"])
-    assert line["idle_gaps"][0] == ["bench.serve.tick", pytest.approx(0.3)]
+    assert dict(line["idle_gaps"]) == pytest.approx(dict(tr.idle_gaps()))
+    assert dict(line["idle_gaps"])["repro.fleet.emit"] == pytest.approx(0.14)
     off = report(cell, records, tr, "cpu", on_chip=False)
     assert "metrics" not in off and "idle_gaps" not in off

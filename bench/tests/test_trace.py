@@ -17,10 +17,11 @@ def _trace():
 
 
 class _Run:
-    def __init__(self, trace, records, traffic, device_kind="TPU v5 lite"):
+    def __init__(self, trace, records, traffic, device_kind="TPU v5 lite",
+                 chips=1):
         self.trace = trace
         self.records = records
-        self.cell = type("C", (), {"traffic": traffic})()
+        self.cell = type("C", (), {"traffic": traffic, "chips": chips})()
         self.device_kind = device_kind
 
 
@@ -58,12 +59,20 @@ def test_breakdown_attributes_idle_time_to_host_spans():
     bd = _trace().breakdown()
     assert bd["device_ops"][0][0] == "linear_pallas.1"
     assert bd["device_ops"][0][1] == pytest.approx(0.2)
-    # Idle [0, 0.1] and [0.3, 0.5] fall in the push span, [0.6, 0.9] in
-    # the tick span (each gap goes to the span open at its middle).
+    # Idle [0, 0.1] and [0.3, 0.45] fall in the push span, [0.45, 0.5]
+    # and [0.6, 0.8] in the tick span, [0.8, 0.9] in none.
     gaps = dict(bd["idle_gaps"])
-    assert gaps["bench.fleet.push"] == pytest.approx(0.3)
-    assert gaps["bench.serve.tick"] == pytest.approx(0.3)
-    assert sum(gaps.values()) == pytest.approx(0.6)
+    assert gaps == pytest.approx({"bench.fleet.push": 0.25,
+                                  "bench.serve.tick": 0.25,
+                                  "outside bench spans": 0.1})
+    # Where one of the program's spans is the innermost, it takes its part.
+    tr = _trace()
+    tr.program_spans = [("repro.fleet.emit", 0.3, 0.44, {})]
+    gaps = dict(tr.breakdown()["idle_gaps"])
+    assert gaps == pytest.approx({"bench.fleet.push": 0.11,
+                                  "repro.fleet.emit": 0.14,
+                                  "bench.serve.tick": 0.25,
+                                  "outside bench spans": 0.1})
 
 
 def _reader(name):
@@ -83,6 +92,42 @@ def test_metric_readers_on_a_fleet_trace():
         pytest.approx(100.0 * moved / 819e9 / 0.2)
     assert _reader("device_idle_share.serve")(run) is None
     assert _reader("masked_step_device_ms")(run) is None
+
+
+def test_roofline_is_reckoned_per_chip():
+    # Four chips, each running the one chip's kernel time over a shard as
+    # large as the one chip's whole fleet, read as the one chip does.
+    records = {"pushes": [(0.0, 0.5, 100), (0.5, 1.0, 100)],
+               "n_streams": 256, "push_width": 1024}
+    one = _Run(_trace(), records, {"method": "linear"})
+    four = _Run(Trace((0.0, 1.0), _trace().ops * 4, []),
+                dict(records, n_streams=4 * 256), {"method": "linear"},
+                chips=4)
+    for name in ("segmenter_roofline", "segmenter_kernel_ms_per_push",
+                 "device_idle_share.fleet"):
+        assert _reader(name)(four) == pytest.approx(_reader(name)(one))
+    assert segmenter_bytes(4 * 256, 1024, 4) == segmenter_bytes(256, 1024)
+    # Each chip's shard is padded to whole 128-lane blocks.
+    assert segmenter_bytes(4 * 100, 8, 4) == 128 * 8 * 13
+
+
+def test_load_trace_keeps_program_spans(tmp_path):
+    import jax
+    import jax.numpy as jnp
+    from bench.core.trace import WINDOW_SPAN, load_trace
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        with jax.profiler.TraceAnnotation(WINDOW_SPAN):
+            with jax.profiler.TraceAnnotation("bench.fleet.push"):
+                with jax.profiler.TraceAnnotation("repro.fleet.push",
+                                                  streams=8):
+                    jnp.ones(8).block_until_ready()
+    finally:
+        jax.profiler.stop_trace()
+    tr = load_trace(str(tmp_path), n_devices=1)
+    assert [s[0] for s in tr.spans] == ["bench.fleet.push"]
+    assert [s[0] for s in tr.program_spans] == ["repro.fleet.push"]
+    assert tr.program_spans[0][3]["streams"] == 8
 
 
 def test_metric_readers_on_a_serve_trace():

@@ -5,10 +5,10 @@
         --seeds 1,2,3 --seconds 30
 
 For each seed, in one process, one traced window of the cell's timed
-path as ``bench/run.py --trace 1`` makes it.  The trace is reduced twice:
-by ``bench/core/trace.py`` as the benchmark does, and for the system's
-``repro.*`` spans by ``bench/core/program_spans.py``.  One JSON line per
-seed: the cell's per-layer metrics and the program-span metrics
+path as ``bench/run.py --trace 1`` makes it, and the trace reduced as
+the benchmark reduces it (``bench/core/trace.py``, which keeps the
+system's ``repro.*`` spans too).  One JSON line per seed: the cell's
+per-layer metrics and the program-span metrics
 (``bench/metrics/fleet_*_ms_per_push.py``, ``serve_*.py``), the seconds,
 count and longest instance of each span, the share of each outer span
 its phases cover, and the device's idle time by the innermost span of
@@ -47,7 +47,6 @@ def traced_window(cell, seed: int, seconds: float, used):
     reduce the trace; the window's records and the reduced trace, which
     carries the program spans as ``program_spans``."""
     import jax
-    from bench.core.program_spans import load_program_spans
     from bench.core.trace import WINDOW_SPAN, load_trace
     system = cell.system.System(cell, seed, used)
     trace_dir = tempfile.mkdtemp(prefix="bench-spans-")
@@ -62,7 +61,6 @@ def traced_window(cell, seed: int, seconds: float, used):
         finally:
             jax.profiler.stop_trace()
         trace = load_trace(trace_dir, n_devices=len(used))
-        trace.program_spans = load_program_spans(trace_dir, trace.window)
     finally:
         shutil.rmtree(trace_dir, ignore_errors=True)
         system.close()
@@ -92,8 +90,7 @@ def report(cell, records, trace, device_kind: str, on_chip: bool) -> dict:
         out["metrics"] = {m: cell.reader(m)(run) for m in wanted}
         out["device"] = {"kind": device_kind, "busy_s": trace.busy_s(),
                          "window_s": trace.window_s()}
-        out["idle_gaps"] = ps.idle_gaps(trace, spans)
-        out["idle_gaps_bench"] = trace.breakdown()["idle_gaps"]
+        out["idle_gaps"] = trace.idle_gaps()
     return out
 
 
