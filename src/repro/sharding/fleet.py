@@ -374,6 +374,14 @@ class FleetStream:
         else:
             self.shard_bytes[d] += sum(len(b) for b in blobs)
 
+    @staticmethod
+    def _fetch(d: int, events):
+        """Shard ``d``'s event planes on the host: the host's wait for its
+        kernel and the copy, which the packer would otherwise make."""
+        with span("repro.fleet.fetch", shard=d,
+                  bytes=sum(x.nbytes for x in events)):
+            return jax.device_get(events)
+
     def _emit(self, ems, events, rows=None, close=False):
         """Step each row block's emitter on its rows of the host
         ``events`` and values ``rows`` (and flush it, with ``close``),
@@ -409,19 +417,16 @@ class FleetStream:
             shard_events = []
             for d, seg in enumerate(self._segs):
                 rows = y[d * self._rows:(d + 1) * self._rows]
-                with span("repro.fleet.put"):
+                with span("repro.fleet.put", shard=d):
                     shard = jax.device_put(rows, self.devices[d])
-                with span("repro.fleet.segment"):
+                with span("repro.fleet.segment", shard=d):
                     shard_events.append((rows, seg.push(shard)))
             out: List = []
             for d, (ems, (rows, events)) in enumerate(zip(self._ems,
                                                           shard_events)):
-                # The host's wait for the kernel and the copy of the
-                # event planes, which the packer would otherwise make.
-                with span("repro.fleet.fetch",
-                          bytes=sum(x.nbytes for x in events)):
-                    events = jax.device_get(events)
-                with span("repro.fleet.emit", blocks=len(self._blocks)):
+                events = self._fetch(d, events)
+                with span("repro.fleet.emit", shard=d,
+                          blocks=len(self._blocks)):
                     blobs, _ = self._emit(ems, events, rows)
                 self._account(d, blobs)
                 out.extend(blobs)
@@ -440,8 +445,10 @@ class FleetStream:
             finals = [seg.finish() for seg in self._segs]
             out: List = []
             for d, (ems, events) in enumerate(zip(self._ems, finals)):
-                blobs, tails = self._emit(ems, jax.device_get(events),
-                                          close=True)
+                events = self._fetch(d, events)
+                with span("repro.fleet.emit", shard=d,
+                          blocks=len(self._blocks)):
+                    blobs, tails = self._emit(ems, events, close=True)
                 self._account(d, blobs)
                 self._account(d, tails)
                 if self.protocol == "twostreams":

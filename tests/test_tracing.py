@@ -8,6 +8,9 @@ may open inside a loop over slots: the count of spans a tick opens must
 not grow with the slot plane.
 """
 
+import json
+import os
+import subprocess
 import sys
 import time
 from collections import Counter
@@ -84,13 +87,20 @@ def test_fleet_push_spans(tmp_path, monkeypatch):
     assert first == list(FLEET_PHASES)
     # The fetch moves the event planes: a flag and two float32 per
     # stream and released column.
-    fetched = [s[3]["bytes"] for s in spans if s[0] == "repro.fleet.fetch"]
+    fetched = [s[3]["bytes"] for s in _within(spans, pushes)
+               if s[0] == "repro.fleet.fetch"]
     assert sum(fetched) == S * 2 * W * (1 + 4 + 4)
-    # The emitter span names its row blocks; each block's worker opens
-    # one span inside it, on whichever thread ran it.
+    # Every per-shard span names its shard; the emitter span names its
+    # row blocks too, and each block's worker opens one span inside it,
+    # on whichever thread ran it.  ``finish`` fetches and packs once
+    # more.
     blocks = len(fs._blocks)
+    for name in FLEET_PHASES:
+        assert all(s[3].get("shard") == 0 for s in spans if s[0] == name)
     emits = [s for s in spans if s[0] == "repro.fleet.emit"]
-    assert emits and all(s[3] == {"blocks": blocks} for s in emits)
+    assert len(emits) == 3
+    assert all(s[3] == {"shard": 0, "blocks": blocks} for s in emits)
+    assert _inside(spans, "repro.fleet.finish")["repro.fleet.fetch"] == 1
     for _, a, b, _ in pushes:
         assert sum(1 for n, c, d, _ in spans if n == "repro.fleet.emit_block"
                    and a <= c and d <= b) == blocks * fs.n_devices
@@ -98,6 +108,53 @@ def test_fleet_push_spans(tmp_path, monkeypatch):
                if s[0] == "repro.fleet.emit_block"
                and pushes[0][1] <= s[1] and s[2] <= pushes[0][2]) == S
     assert inside["repro.fleet.emit_block"] == 2 * blocks * fs.n_devices
+
+
+def _within(spans, outers):
+    """The spans that lie inside one of ``outers``."""
+    return [s for s in spans if any(a <= s[1] and s[2] <= b
+                                    for _, a, b, _ in outers)]
+
+
+FOUR_DEVICES = r"""
+import json, os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import jax
+import numpy as np
+from bench.core import program_spans as ps
+from repro.sharding.fleet import FleetStream
+assert jax.device_count() == 4, jax.devices()
+y = np.cumsum(np.random.default_rng(5).normal(0, 0.6, (32, 128)),
+              1).astype(np.float32)
+fs = FleetStream("angle", "twostreams", 32, 1.0, block_s=8, block_t=32)
+fs.push(y[:, :64])              # compiles outside the trace
+jax.profiler.start_trace(sys.argv[1])
+fs.push(y[:, 64:])
+jax.profiler.stop_trace()
+print(json.dumps([[n, a, b, {k: v for k, v in args.items()
+                             if isinstance(v, (int, float, str))}]
+                  for n, a, b, args in ps.load_program_spans(sys.argv[1])]))
+"""
+
+
+def test_fleet_push_spans_on_four_devices(tmp_path):
+    """A push over four devices fetches and packs each shard once, and
+    each per-shard span names its shard (``fleet_pack_overlap`` groups
+    the emit spans by it)."""
+    root = Path(__file__).resolve().parents[1]
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               PYTHONPATH=f"{root}:{root / 'src'}")
+    out = subprocess.run([sys.executable, "-c", FOUR_DEVICES,
+                          str(tmp_path)], capture_output=True, text=True,
+                         timeout=300, env=env, cwd=root)
+    assert out.returncode == 0, out.stderr[-3000:]
+    spans = [tuple(s) for s in json.loads(out.stdout.splitlines()[-1])]
+    assert [s[0] for s in spans].count("repro.fleet.push") == 1
+    for name in FLEET_PHASES:
+        shards = sorted(s[3]["shard"] for s in spans if s[0] == name)
+        assert shards == [0, 1, 2, 3], name
+    assert {s[3]["blocks"] for s in spans
+            if s[0] == "repro.fleet.emit"} == {1}
 
 
 def test_fleet_store_span(tmp_path):
