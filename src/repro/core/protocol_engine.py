@@ -68,7 +68,8 @@ __all__ = [
     "protocol_descriptors", "protocol_point_metrics", "protocol_nbytes",
     "metrics_from_descriptors", "descriptors_point_metrics",
     "batched_point_metrics", "encode_batch", "to_method_outputs",
-    "ProtocolEmitter", "WireRecords", "decode_records", "decode_batch",
+    "ProtocolEmitter", "SlotPlaneEmitter", "WireRecords", "decode_records",
+    "decode_batch",
 ]
 
 ENGINE_PROTOCOLS = ("implicit", "twostreams", "singlestream",
@@ -663,6 +664,11 @@ class ProtocolEmitter:
     per-event Python runs even in the dense-event worst case (every point
     a singleton); quiet fleets cost O(events), not O(S).
 
+    Its streams share one column clock: how a chunk is taken in
+    (``_take``) and which values are kept (``_gather_runs``, ``_trim``)
+    follow from that; :class:`SlotPlaneEmitter` replaces those three for
+    a plane of streams with clocks of their own and shares the rest.
+
     ``knot_kind`` extends to the deferred methods: ``"continuous"``
     (joint knots on the connected polyline, opening knot on the first
     line) and ``"mixed"`` (joint/disjoint detected from line continuity,
@@ -673,6 +679,13 @@ class ProtocolEmitter:
     def __init__(self, protocol: str, n_streams: int, *,
                  knot_kind: str = "disjoint", t0: float = 0.0,
                  dt: float = 1.0, burst_cap: int = 127):
+        self._init_codec(protocol, n_streams, knot_kind, t0, dt, burst_cap)
+        self._ybuf = np.zeros((n_streams, 0), np.float64)
+        self._ybase = 0            # absolute position of _ybuf[:, 0]
+        self._epos = 0             # absolute position of next event column
+
+    def _init_codec(self, protocol: str, n_streams: int, knot_kind: str,
+                    t0: float, dt: float, burst_cap: int) -> None:
         if protocol not in ENGINE_PROTOCOLS:
             raise ValueError(f"unknown protocol {protocol!r}; "
                              f"have {sorted(ENGINE_PROTOCOLS)}")
@@ -695,9 +708,6 @@ class ProtocolEmitter:
         self._pend_len = np.zeros(S, np.int64)
         self._pend_y2 = np.zeros(S, np.float64)    # mixed: deferred y''
         self._has_y2 = np.zeros(S, bool)
-        self._ybuf = np.zeros((S, 0), np.float64)
-        self._ybase = 0            # absolute position of _ybuf[:, 0]
-        self._epos = 0             # absolute position of next event column
         self._finished = False
 
     # -- plumbing -----------------------------------------------------------
@@ -729,21 +739,12 @@ class ProtocolEmitter:
         byte positions in one shot.
         """
         lens = np.asarray(lens, np.int64)
-        total = int(lens.sum())
-        if total == 0:
+        if not lens.sum():
             return np.empty(0, np.float64), np.empty(0, np.int64)
         lo = np.asarray(lo, np.int64)
         have_lo = self._ybase
-        have_hi = self._ybase + self._ybuf.shape[1]
-        bad = (lo < have_lo) | (lo + lens > have_hi)
-        if bad.any():
-            b = int(np.flatnonzero(bad)[0])
-            raise ValueError(
-                f"record needs values [{int(lo[b])}, {int(lo[b] + lens[b])})"
-                f" but only [{have_lo}, {have_hi}) were pushed — pass "
-                f"y_chunk no later than its events")
-        within = np.arange(total, dtype=np.int64) \
-            - np.repeat(np.cumsum(lens) - lens, lens)
+        within = _run_offsets(lo, lens, have_lo,
+                              have_lo + self._ybuf.shape[1])
         vals = self._ybuf[np.repeat(rows, lens),
                           np.repeat(lo - have_lo, lens) + within]
         return vals, within
@@ -771,15 +772,16 @@ class ProtocolEmitter:
                 f"exceeds the {n_cap}-point counter range — segment with "
                 f"max_run=PROTOCOL_CAPS[{self.protocol!r}]")
 
-    def _event_geometry(self, ss, jj, a, v) -> "_ChunkEvents":
+    def _event_geometry(self, ss, es, a, v) -> "_ChunkEvents":
         """Per-event codec geometry, resolved without a Python walk.
 
+        ``ss``/``es`` are each event's stream and absolute break
+        position, stream-major and time-sorted within a stream.
         Cross-event state (previous break position / line, segment
         ordinal) comes from the carried ``(S,)`` arrays for each stream's
         first event of the chunk and from a one-element shift for the
         rest — events are stream-major so a stream's events are adjacent.
         """
-        es = self._epos + jj.astype(np.int64)
         As = a / self.dt
         Bs = v - a * es - As * self.t0
         N = len(ss)
@@ -988,29 +990,13 @@ class ProtocolEmitter:
         """Consume new event columns / value columns; return new bytes."""
         if self._finished:
             raise RuntimeError("step_chunk after flush()")
-        if y_chunk is not None:
-            y = np.asarray(y_chunk, np.float64)
-            if y.ndim != 2 or y.shape[0] != self.n_streams:
-                raise ValueError(f"y_chunk must be ({self.n_streams}, n); "
-                                 f"got {y.shape}")
-            self._ybuf = np.concatenate([self._ybuf, y], axis=1)
-        if events is not None and events.breaks.shape[0] != self.n_streams:
-            raise ValueError(f"events must cover ({self.n_streams}, w) "
-                             f"streams; got {events.breaks.shape}")
+        found = self._take(events, y_chunk)
+        if found is None:
+            empty = [b""] * self.n_streams
+            return [(b, b"") for b in empty] \
+                if self.protocol == "twostreams" else empty
+        ev = self._event_geometry(*found)
         p = self.protocol
-        if events is None or not events.breaks.shape[1]:
-            empty = [b""] * self.n_streams
-            return [(b, b"") for b in empty] if p == "twostreams" else empty
-        brk = np.asarray(events.breaks, bool)
-        ss, jj = np.nonzero(brk)      # row-major: stream-major, time-sorted
-        if not len(ss):
-            self._epos += brk.shape[1]
-            self._trim()
-            empty = [b""] * self.n_streams
-            return [(b, b"") for b in empty] if p == "twostreams" else empty
-        ev = self._event_geometry(ss, jj,
-                                  np.asarray(events.a, np.float64)[ss, jj],
-                                  np.asarray(events.v, np.float64)[ss, jj])
         if p == "implicit":
             packed = self._pack_implicit(ev)
         elif p == "twostreams":
@@ -1027,23 +1013,52 @@ class ProtocolEmitter:
         self._prev_B[gs] = ev.Bs[ev.glast]
         if p != "singlestreamv":      # its packer manages the burst window
             self._pend_start[gs] = ev.es[ev.glast] + 1
-        self._epos += brk.shape[1]
         self._trim()
         if p == "twostreams":
             (seg, sizes), (single, ssizes) = packed
-            return list(zip(self._per_stream(seg, sizes, ss),
-                            self._per_stream(single, ssizes, ss)))
+            return list(zip(self._per_stream(seg, sizes, ev.ss),
+                            self._per_stream(single, ssizes, ev.ss)))
         buf, sizes = packed
-        return self._per_stream(buf, sizes, ss)
+        return self._per_stream(buf, sizes, ev.ss)
+
+    def _take(self, events: Optional[SegmentOutput], y_chunk):
+        """Buffer the value columns and advance the shared column clock
+        past the event columns; the chunk's events as ``(ss, es, a, v)``,
+        or None where it has none."""
+        if y_chunk is not None:
+            y = np.asarray(y_chunk, np.float64)
+            if y.ndim != 2 or y.shape[0] != self.n_streams:
+                raise ValueError(f"y_chunk must be ({self.n_streams}, n); "
+                                 f"got {y.shape}")
+            self._ybuf = np.concatenate([self._ybuf, y], axis=1)
+        if events is not None and events.breaks.shape[0] != self.n_streams:
+            raise ValueError(f"events must cover ({self.n_streams}, w) "
+                             f"streams; got {events.breaks.shape}")
+        if events is None or not events.breaks.shape[1]:
+            return None
+        brk = np.asarray(events.breaks, bool)
+        ss, jj = np.nonzero(brk)      # row-major: stream-major, time-sorted
+        es = self._epos + jj.astype(np.int64)
+        self._epos += brk.shape[1]
+        if not len(ss):
+            self._trim()
+            return None
+        return (ss, es, np.asarray(events.a, np.float64)[ss, jj],
+                np.asarray(events.v, np.float64)[ss, jj])
 
     def flush(self) -> List:
         """Close the stream: trailing bursts and the closing knot."""
         if self._finished:
             raise RuntimeError("flush() called twice")
         self._finished = True
+        return self._close(np.ones(self.n_streams, bool))
+
+    def _close(self, rows: np.ndarray) -> List:
+        """The closing bytes of the streams in the ``(S,)`` mask ``rows``
+        (b"" for the others): trailing bursts and the closing knot."""
         outs = [b""] * self.n_streams
         if self.protocol == "singlestreamv":
-            act = np.flatnonzero(self._pend_len > 0)
+            act = np.flatnonzero(rows & (self._pend_len > 0))
             if len(act):
                 lens = self._pend_len[act]
                 sizes = 1 + 8 * lens
@@ -1060,7 +1075,7 @@ class ProtocolEmitter:
                 self._pend_len[act] = 0
         elif self.protocol == "implicit" \
                 and self.knot_kind in ("disjoint", "mixed"):
-            act = np.flatnonzero(self._k > 0)
+            act = np.flatnonzero(rows & (self._k > 0))
             if len(act):
                 pw = self._has_y2[act] if self.knot_kind == "mixed" \
                     else np.zeros(len(act), bool)
@@ -1081,6 +1096,111 @@ class ProtocolEmitter:
         return outs
 
 
+class SlotPlaneEmitter(ProtocolEmitter):
+    """A :class:`ProtocolEmitter` over the rows of one slot-plane shard,
+    where every row is a stream with a clock of its own.
+
+    The serving plane (:class:`repro.serving.slots.SlotManager`) admits
+    and evicts streams row by row and feeds each row its own number of
+    points a tick, so its rows share no column clock.  ``step_chunk``
+    takes the shard's :class:`~repro.core.jax_pla.MaskedEvents` as
+    :func:`~repro.core.jax_pla.masked_step_chunk` returns them (events
+    tagged with row-local positions) and, as ``y_chunk``, the tick's
+    ``(plane, lengths)``: row ``r`` takes ``plane[r, :lengths[r]]``.
+    ``points[r]`` counts the values row ``r`` took since its reset.
+
+    Values wait in a per-row ring, position ``p`` at ``p % W``.  No record
+    reaches further back from its own break than a ``max_run``-point
+    segment after a partial burst of ``burst_cap`` values, and a break
+    comes at most one plane behind the newest value, so ``W`` is
+    ``max_run + burst_cap`` plus the widest plane taken so far.
+
+    ``reset_rows`` starts rows afresh (admission); ``flush_rows`` closes
+    rows and returns their closing bytes (eviction).  Each row's bytes
+    equal those of a one-stream :class:`ProtocolEmitter` fed that row's
+    stream alone.
+    """
+
+    def __init__(self, protocol: str, n_rows: int, *, max_run: int,
+                 knot_kind: str = "disjoint", burst_cap: int = 127):
+        self._init_codec(protocol, n_rows, knot_kind, 0.0, 1.0, burst_cap)
+        self._reach = max_run + burst_cap
+        self.points = np.zeros(n_rows, np.int64)
+        self._ring = np.zeros((n_rows, 0), np.float64)
+
+    def reset_rows(self, rows) -> None:
+        """Start the rows of the ``(R,)`` mask ``rows`` as fresh streams."""
+        rows = np.asarray(rows, bool)
+        for state in (self._k, self._pend_start, self._pend_len,
+                      self.points, self._prev_A, self._prev_B,
+                      self._pend_y2, self._has_y2):
+            state[rows] = 0
+        self._prev_end[rows] = -1
+
+    def flush_rows(self, rows) -> List:
+        """Close the rows of the ``(R,)`` mask ``rows``: their closing
+        bytes, in row order."""
+        rows = np.asarray(rows, bool)
+        outs = self._close(rows)
+        return [outs[r] for r in np.flatnonzero(rows)]
+
+    def _take(self, events, y_chunk):
+        if y_chunk is not None:
+            self._push(*y_chunk)
+        if events is None:
+            return None
+        ss, jj = np.nonzero(np.asarray(events.ev, bool))
+        if not len(ss):
+            return None
+        es = np.asarray(events.pos, np.int64)[ss, jj]
+        if ((es <= self._prev_end[ss]) | (es >= self.points[ss])).any():
+            raise ValueError("an event lies at or before its row's last "
+                             "break, or at or past its newest value")
+        return (ss, es, np.asarray(events.a, np.float64)[ss, jj],
+                np.asarray(events.v, np.float64)[ss, jj])
+
+    def _push(self, plane, lengths) -> None:
+        """Write each row's valid prefix into the ring."""
+        plane = np.asarray(plane)
+        lengths = np.asarray(lengths, np.int64)
+        if plane.ndim != 2 or plane.shape[0] != self.n_streams \
+                or lengths.shape != (self.n_streams,):
+            raise ValueError(f"plane must be ({self.n_streams}, n) and "
+                             f"lengths ({self.n_streams},); got "
+                             f"{plane.shape} and {lengths.shape}")
+        if self._reach + plane.shape[1] > self._ring.shape[1]:
+            self._grow(self._reach + plane.shape[1])
+        rr, jj = np.nonzero(np.arange(plane.shape[1]) < lengths[:, None])
+        W = self._ring.shape[1]
+        self._ring[rr, (self.points[rr] + jj) % W] = plane[rr, jj]
+        self.points += lengths
+
+    def _grow(self, W: int) -> None:
+        """Widen the ring to ``W`` positions, keeping what it holds."""
+        old = self._ring.shape[1]
+        ring = np.zeros((self.n_streams, W), np.float64)
+        if old:
+            p = self.points[:, None] - old + np.arange(old)
+            rr, jj = np.nonzero(p >= 0)
+            ring[rr, p[rr, jj] % W] = self._ring[rr, p[rr, jj] % old]
+        self._ring = ring
+
+    def _gather_runs(self, rows, lo, lens):
+        lens = np.asarray(lens, np.int64)
+        if not lens.sum():
+            return np.empty(0, np.float64), np.empty(0, np.int64)
+        lo = np.asarray(lo, np.int64)
+        hi = self.points[rows]
+        W = self._ring.shape[1]
+        within = _run_offsets(lo, lens, np.maximum(hi - W, 0), hi)
+        vals = self._ring[np.repeat(rows, lens),
+                          (np.repeat(lo, lens) + within) % W]
+        return vals, within
+
+    def _trim(self) -> None:
+        """Nothing to drop: the ring overwrites what no record reaches."""
+
+
 class _ChunkEvents(NamedTuple):
     """One chunk's finalized events, flat and stream-major, with the
     cross-event codec geometry already resolved (see
@@ -1098,6 +1218,23 @@ class _ChunkEvents(NamedTuple):
     gstart: np.ndarray  # (G,) index of each stream's first event
     glast: np.ndarray   # (G,) index of each stream's last event
     counts: np.ndarray  # (G,) events per stream
+
+
+def _run_offsets(lo, lens, have_lo, have_hi) -> np.ndarray:
+    """Each value's index inside its run ``[lo, lo + lens)``, once every
+    run is checked to lie inside the held values ``[have_lo, have_hi)``
+    (scalars, or one bound per run)."""
+    bad = (lo < have_lo) | (lo + lens > have_hi)
+    if bad.any():
+        b = int(np.flatnonzero(bad)[0])
+        held_lo, held_hi = (int(np.broadcast_to(h, lo.shape)[b])
+                            for h in (have_lo, have_hi))
+        raise ValueError(
+            f"record needs values [{int(lo[b])}, {int(lo[b] + lens[b])})"
+            f" but only [{held_lo}, {held_hi}) are held — pass values no "
+            f"later than their events")
+    return np.arange(int(lens.sum()), dtype=np.int64) \
+        - np.repeat(np.cumsum(lens) - lens, lens)
 
 
 def _excl_offsets(sizes: np.ndarray):

@@ -4,7 +4,7 @@ Three layers over the fleet engine:
 
 - :mod:`repro.serving.slots` — padded per-device slot plane with
   generation-tagged admission/eviction (masked per-row-clock segmenter
-  rows + per-slot wire emitters);
+  rows + one wire emitter a shard);
 - :mod:`repro.serving.ticks` — out-of-phase arrivals batched into
   fixed-shape per-tick pushes, bounded ingress queues, shed-or-block
   backpressure;

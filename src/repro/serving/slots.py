@@ -11,19 +11,22 @@ segmenter shard per device — and maps short-lived streams onto slots:
   (:func:`repro.core.jax_pla.masked_step_chunk`) rebuilds the slot's
   carry row from the stream's own first point, so a recycled slot is
   structurally incapable of leaking the previous occupant's segmenter
-  state; the codec geometry is fresh too (a new per-slot
-  :class:`~repro.core.protocol_engine.ProtocolEmitter` per admission).
+  state; the codec geometry is fresh too (admission resets the slot's
+  row of its shard's
+  :class:`~repro.core.protocol_engine.SlotPlaneEmitter`).
 - **step** pushes one ``(S_pad, n)`` tick plane with per-slot valid
   lengths; the jit shape never changes with churn (empty slots ride
   along as length-0 rows with ε = :data:`INACTIVE_EPS`).
-- **evict** force-closes the slot's trailing run on device and drains
-  the slot's wire emitter; the returned bytes are bit-identical to the
+- **evict** force-closes the slot's trailing run on device and flushes
+  the slot's row of the wire emitter; the returned bytes are
+  bit-identical to the
   offline :func:`~repro.core.protocol_engine.encode_batch` of the
   stream's own data (pinned in tests/test_serving.py).
 
 Wire framing is per-stream and stream-local (position 0 = the stream's
 first point), so slot placement and tick phasing leave no trace in the
-bytes.
+bytes.  Each shard packs its rows' bytes in one
+:class:`~repro.core.protocol_engine.SlotPlaneEmitter` call a tick.
 
 ``admit``, ``evict``, each ε-plane upload and the three phases of
 ``step`` (dispatch, fetch, emit: one span per shard, none per slot) open
@@ -42,7 +45,7 @@ from jax.profiler import TraceAnnotation as span
 
 from repro.core import jax_pla
 from repro.core.evaluate import METHOD_KNOT_KINDS
-from repro.core.protocol_engine import ProtocolEmitter
+from repro.core.protocol_engine import SlotPlaneEmitter
 
 __all__ = ["INACTIVE_EPS", "FleetFull", "Slot", "EvictReport",
            "SlotManager"]
@@ -65,10 +68,7 @@ class Slot:
     index: int                          # global row in the slot plane
     stream_id: Optional[str] = None     # None = free
     generation: int = 0                 # bumped at every admission
-    points: int = 0                     # consumed since admission
-    emitted: int = 0                    # event columns fed to the emitter
     nbytes: int = 0                     # wire bytes emitted since admission
-    emitter: Optional[ProtocolEmitter] = None
 
     @property
     def live(self) -> bool:
@@ -140,6 +140,12 @@ class SlotManager:
             self._states.append(dataclasses.replace(
                 st, carry=moved[0], started=moved[1], pos=moved[2],
                 eps=moved[3]))
+        # One wire emitter a shard; a slot's points since admission are
+        # its row's clock there.
+        self._emitters = [
+            SlotPlaneEmitter(protocol, self.rows_per_shard, max_run=max_run,
+                             knot_kind=self.knot_kind, burst_cap=burst_cap)
+            for _ in self.devices]
         self.slots: List[Slot] = [Slot(index=i)
                                   for i in range(self.capacity)]
         self._free: List[int] = list(range(self.capacity - 1, -1, -1))
@@ -170,12 +176,9 @@ class SlotManager:
         slot = self.slots[i]
         slot.stream_id = stream_id
         slot.generation += 1
-        slot.points = 0
-        slot.emitted = 0
         slot.nbytes = 0
-        slot.emitter = ProtocolEmitter(self.protocol, 1,
-                                       knot_kind=self.knot_kind,
-                                       burst_cap=self.burst_cap)
+        d, r = divmod(i, self.rows_per_shard)
+        self._emitters[d].reset_rows(self._row_mask(r))
         self._by_stream[stream_id] = i
         self._set_row_eps(i, self.eps0 if eps is None else float(eps))
         if self.store is not None:
@@ -207,35 +210,40 @@ class SlotManager:
             raise KeyError(f"stream {stream_id!r} is not admitted")
         slot = self.slots[i]
         d, r = divmod(i, self.rows_per_shard)
-        mask = np.zeros((self.rows_per_shard,), bool)
-        mask[r] = True
-        self._states[d], (ev, pos, a_f, v_f) = jax_pla.masked_flush_rows(
-            self._states[d], mask)
+        mask = self._row_mask(r)
+        self._states[d], final = jax_pla.masked_flush_rows(self._states[d],
+                                                           mask)
+        em = self._emitters[d]
+        points = int(em.points[r])
         tail = b""
-        if slot.points > 0:
-            assert bool(np.asarray(ev)[r])
-            part = self._feed_slot(
-                slot, np.asarray(pos)[r:r + 1, None],
-                np.asarray(a_f)[r:r + 1, None],
-                np.asarray(v_f)[r:r + 1, None],
-                np.ones((1, 1), bool), None)
-            drained = slot.emitter.flush()
-            tail = self._blob(part) \
-                + b"".join(self._blob(p) for p in drained)
+        if points > 0:
+            # The forced break at the row's last point, as one more event
+            # column of the shard; then the row's closing bytes.
+            final = jax_pla.MaskedEvents(*(np.asarray(x)[:, None]
+                                           for x in final))
+            assert bool(final.ev[r, 0])
+            part = em.step_chunk(final)[r]
+            closing = em.flush_rows(mask)[0]
+            tail = self._blob(part) + self._blob(closing)
             if self.store is not None:
-                self._archive(slot, [part, *drained])
+                self._archive(slot, [part, closing])
             slot.nbytes += len(tail)
             self.total_bytes += len(tail)
         if self.store is not None:
             self.store.close([self._store_key(slot)])
         rep = EvictReport(stream_id=stream_id, slot=i,
-                          generation=slot.generation, points=slot.points,
+                          generation=slot.generation, points=points,
                           nbytes=slot.nbytes, tail=tail)
         slot.stream_id = None
-        slot.emitter = None
         self._set_row_eps(i, INACTIVE_EPS)
         self._free.append(i)
         return rep
+
+    def _row_mask(self, r: int) -> np.ndarray:
+        """A shard's ``(rows_per_shard,)`` mask selecting row ``r``."""
+        mask = np.zeros((self.rows_per_shard,), bool)
+        mask[r] = True
+        return mask
 
     # -- ε plane -----------------------------------------------------------
 
@@ -307,66 +315,26 @@ class SlotManager:
         for d, out in outs.items():
             # Where the host waits for the shard's step.
             with span("repro.slots.fetch"):
-                ev = np.asarray(out.ev)
-                pos = np.asarray(out.pos)
-                a = np.asarray(out.a)
-                v = np.asarray(out.v)
-            with span("repro.slots.emit"):
-                for r in range(R):
-                    i = d * R + r
-                    c = int(lengths[i])
-                    if c == 0:
+                out = jax_pla.MaskedEvents(*(np.asarray(x) for x in out))
+            rows = slice(d * R, (d + 1) * R)
+            hit = np.flatnonzero(out.ev.any(axis=1))
+            with span("repro.slots.emit", rows=int(hit.size),
+                      events=int(np.count_nonzero(out.ev))):
+                parts = self._emitters[d].step_chunk(
+                    out, (plane[rows], lengths[rows]))
+                # Only a row with events has bytes.
+                for r in hit.tolist():
+                    blob = self._blob(parts[r])
+                    if not blob:
                         continue
-                    slot = self.slots[i]
-                    js = np.flatnonzero(ev[r])
-                    part = self._feed_slot(slot, pos[r:r + 1, js],
-                                           a[r:r + 1, js], v[r:r + 1, js],
-                                           np.ones((1, js.size), bool),
-                                           plane[i, :c][None])
-                    slot.points += c
-                    self.total_points += c
-                    blob = self._blob(part)
-                    if blob:
-                        if self.store is not None:
-                            self._archive(slot, [part])
-                        slot.nbytes += len(blob)
-                        self.total_bytes += len(blob)
-                        wire.append((slot.stream_id, slot.generation, blob))
+                    slot = self.slots[d * R + r]
+                    if self.store is not None:
+                        self._archive(slot, [parts[r]])
+                    slot.nbytes += len(blob)
+                    self.total_bytes += len(blob)
+                    wire.append((slot.stream_id, slot.generation, blob))
+        self.total_points += int(lengths.sum())
         return wire
-
-    def _feed_slot(self, slot: Slot, pos, a, v, ev, values):
-        """Feed one slot's new events/values to its wire emitter.
-
-        Events arrive position-tagged (row-local); the emitter wants
-        aligned columns, so they are scattered onto the contiguous span
-        of newly finalized positions ``[slot.emitted, frontier)``.
-        Returns the emitter's raw per-stream part (``bytes``, or the
-        twostreams ``(segment, singleton)`` pair — callers flatten with
-        :meth:`_blob` for the wire and keep the pair for the store).
-        """
-        c = 0 if values is None else values.shape[1]
-        # Positions < frontier are finalized: the engine emits events for
-        # local position p-1 when consuming p (the close event for p-1
-        # arrives via evict's forced flush, where the frontier is points).
-        frontier = slot.points + c - 1 if values is not None \
-            else slot.points
-        w = max(frontier - slot.emitted, 0)
-        events = None
-        if w > 0:
-            brk = np.zeros((1, w), bool)
-            A = np.zeros((1, w), np.float32)
-            V = np.zeros((1, w), np.float32)
-            cols = np.asarray(pos)[ev] - slot.emitted
-            assert (cols >= 0).all() and (cols < w).all()
-            brk[0, cols] = True
-            A[0, cols] = np.asarray(a)[ev]
-            V[0, cols] = np.asarray(v)[ev]
-            events = jax_pla.SegmentOutput(brk, A, V)
-            slot.emitted += w
-        elif not np.asarray(ev).any() and c == 0:
-            return b""
-        parts = slot.emitter.step_chunk(events, values)
-        return parts[0] if parts else b""
 
     @staticmethod
     def _blob(part) -> bytes:

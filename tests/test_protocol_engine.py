@@ -6,8 +6,9 @@ produce (a) wire bytes identical to the legacy ``encode_*`` codecs, and
 (b) §4.2 per-point metrics equal to ``metrics.point_metrics`` — both run
 on the *same* segmentation via the ``to_method_outputs`` translation.
 Also covers the fused reconstruction/error kernel path, the fixed-slot
-record expansion, the streaming ``ProtocolEmitter``, and the 2^24
-absolute-time guard of the jnp reference segmenters.
+record expansion, the streaming ``ProtocolEmitter``, the serving
+plane's ``SlotPlaneEmitter`` against one ``ProtocolEmitter`` per row, and
+the 2^24 absolute-time guard of the jnp reference segmenters.
 """
 
 import dataclasses
@@ -17,9 +18,10 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import jax_pla
-from repro.core.evaluate import evaluate_batched
+from repro.core.evaluate import METHOD_KNOT_KINDS, evaluate_batched
 from repro.core.metrics import point_metrics
 from repro.core.protocol_engine import (ENGINE_PROTOCOLS, ProtocolEmitter,
+                                        SlotPlaneEmitter,
                                         batched_point_metrics, encode_batch,
                                         protocol_nbytes,
                                         protocol_point_metrics,
@@ -246,6 +248,106 @@ def test_emitter_fused_packer_deferred_kinds_at_s64(method):
     for s, b in enumerate(em.flush()):
         got[s] += b
     assert got == offline
+
+
+class _OneRow:
+    """One stream's own one-row ``ProtocolEmitter``, fed as the serving
+    plane fed each slot before it had a plane emitter: a tick's events,
+    tagged with row-local positions, are scattered onto the columns
+    finalized since the last feed (the close comes with no values)."""
+
+    def __init__(self, protocol, knot_kind):
+        self.em = ProtocolEmitter(protocol, 1, knot_kind=knot_kind)
+        self.points = 0
+        self.emitted = 0
+
+    def feed(self, pos, a, v, values=None):
+        c = 0 if values is None else len(values)
+        frontier = self.points + c - 1 if values is not None else self.points
+        w = max(frontier - self.emitted, 0)
+        events = None
+        if w:
+            brk = np.zeros((1, w), bool)
+            A = np.zeros((1, w), np.float32)
+            V = np.zeros((1, w), np.float32)
+            brk[0, pos - self.emitted] = True
+            A[0, pos - self.emitted] = a
+            V[0, pos - self.emitted] = v
+            events = jax_pla.SegmentOutput(brk, A, V)
+            self.emitted += w
+        self.points += c
+        return self.em.step_chunk(
+            events, None if values is None else values[None])[0]
+
+
+def _host(events):
+    return jax_pla.MaskedEvents(*(np.asarray(x) for x in events))
+
+
+@pytest.mark.parametrize("method,protocol", [
+    ("linear", "singlestream"), ("swing", "twostreams"),
+    ("angle", "singlestreamv"), ("disjoint", "implicit"),
+    ("swing", "implicit")])
+def test_slot_plane_emitter_matches_one_emitter_per_row(method, protocol):
+    """Per row and per tick, a shard's one ``SlotPlaneEmitter`` gives the
+    bytes (twostreams pairs included) that one ``ProtocolEmitter`` per row
+    gives, through ragged lengths, idle rows, segments that reach
+    ``max_run``, planes that widen the ring, and rows closed and started
+    afresh mid-run."""
+    R, max_run, eps = 6, 64, 0.8
+    rng = np.random.default_rng(41)
+    kk = METHOD_KNOT_KINDS.get(method, "disjoint")
+    T = 3000
+    src = np.cumsum(rng.normal(0, 0.6, (R, T)), axis=1)
+    src[2] = rng.normal(0, 25, T)                   # singletons and bursts
+    src[3] = 0.25 * np.arange(T)                    # runs capped at max_run
+    src = src.astype(np.float32)
+    cursor = np.zeros(R, np.int64)
+    st = jax_pla.masked_init_state(method, R, np.full(R, eps, np.float32),
+                                   max_run=max_run)
+    plane_em = SlotPlaneEmitter(protocol, R, max_run=max_run, knot_kind=kk)
+    rows = [_OneRow(protocol, kk) for _ in range(R)]
+    empty = (b"", b"") if protocol == "twostreams" else b""
+
+    def close(r):
+        nonlocal st
+        mask = np.arange(R) == r
+        st, final = jax_pla.masked_flush_rows(st, mask)
+        final = jax_pla.MaskedEvents(*(np.asarray(x)[:, None]
+                                       for x in final))
+        got = plane_em.step_chunk(final)
+        assert all(got[i] == empty for i in range(R) if i != r)
+        want = rows[r].feed(final.pos[r, :1], final.a[r, :1],
+                            final.v[r, :1]) if rows[r].points else empty
+        assert got[r] == want
+        assert plane_em.flush_rows(mask) == rows[r].em.flush()
+        plane_em.reset_rows(mask)
+        rows[r] = _OneRow(protocol, kk)
+
+    for tick in range(48):
+        if tick % 9 == 8:
+            close(int(rng.integers(R)))
+        n = int(rng.choice([1, 7] if tick < 12 else [1, 7, 16, 33]))
+        lengths = rng.integers(0, n + 1, R)
+        if 14 <= tick < 22:
+            lengths[4] = 0                          # idle for eight ticks
+        y = np.zeros((R, n), np.float32)
+        for r in range(R):
+            y[r, :lengths[r]] = src[r, cursor[r]:cursor[r] + lengths[r]]
+        cursor += lengths
+        st, out = jax_pla.masked_step_chunk(st, y, lengths)
+        out = _host(out)
+        got = plane_em.step_chunk(out, (y, lengths))
+        for r in range(R):
+            if not lengths[r]:
+                assert got[r] == empty
+                continue
+            js = np.flatnonzero(out.ev[r])
+            assert got[r] == rows[r].feed(out.pos[r, js], out.a[r, js],
+                                          out.v[r, js], y[r, :lengths[r]])
+    assert np.array_equal(plane_em.points, [o.points for o in rows])
+    for r in range(R):
+        close(r)
 
 
 def test_records_to_events_roundtrip_and_kernel_reconstruct():

@@ -48,72 +48,124 @@ def _walk(rng, n):
     return np.cumsum(rng.normal(0, 0.6, n)).astype(np.float32)
 
 
+def _offline_parts(y, method="linear", protocol="singlestream",
+                   eps=EPS, max_run=256):
+    """The offline encode of one stream: its bytes, or the twostreams
+    ``(segment, singleton)`` pair."""
+    seg = BATCHED_SEGMENTERS[method](y[None], eps, max_run=max_run)
+    return encode_batch(seg, y[None], protocol,
+                        METHOD_KNOT_KINDS.get(method, "disjoint"))[0]
+
+
 def _offline_bytes(y, method="linear", protocol="singlestream",
                    eps=EPS, max_run=256) -> bytes:
-    seg = BATCHED_SEGMENTERS[method](y[None], eps, max_run=max_run)
-    recs = encode_batch(seg, y[None], protocol,
-                        METHOD_KNOT_KINDS.get(method, "disjoint"))
-    return b"".join(recs[0]) if isinstance(recs[0], tuple) else recs[0]
+    rec = _offline_parts(y, method, protocol, eps, max_run)
+    return b"".join(rec) if isinstance(rec, tuple) else rec
 
 
 # ---------------------------------------------------------------------------
 # Slot recycling: generation N output == fresh run, bytes == offline
 # ---------------------------------------------------------------------------
 
-def _churn_body(seed, n_ops, method="linear", protocol="singlestream"):
-    """Random admit/evict/push; checks every evicted stream's lifetime
-    wire against the offline encode of its own accepted data."""
+CHURN_COMBOS = [("linear", "singlestream"), ("swing", "twostreams"),
+                ("angle", "singlestreamv"), ("disjoint", "implicit")]
+
+
+def _churn_body(seed, n_ops, method="linear", protocol="singlestream",
+                capacity=4):
+    """Random admit / evict / offer-and-tick over a ServeLoop; checks
+    every evicted stream's lifetime wire against the offline encode of
+    its own accepted data (for twostreams, the archived pair against the
+    offline pair).
+
+    Ticks take planes of random width; streams go quiet for several
+    ticks or are offered nothing; a third of them follow exact ramps, so
+    their segments reach ``max_run``; offers outpace the ticks, so evicts
+    drain queued points; and every draw ends by evicting a stream and
+    admitting another into its slot."""
+    from repro.store import SegmentStore
     rng = np.random.default_rng(seed)
-    mgr = SlotManager(method, protocol, capacity=4, eps0=EPS, max_run=64)
+    store = SegmentStore(protocol, eps=EPS) \
+        if protocol == "twostreams" else None
+    mgr = SlotManager(method, protocol, capacity=capacity, eps0=EPS,
+                      max_run=64, store=store)
+    loop = ServeLoop(mgr, tick_width=16, queue_cap=1 << 16)
     fed = {}                    # stream_id -> list of accepted chunks
     wire = {}                   # stream_id -> accumulated bytes
-    next_id = 0
+    quiet = {}                  # stream_id -> ticks left without offers
+    ramp = {}                   # stream_id -> [next value, slope] or None
     live = []
 
+    def admit():
+        sid = f"s{len(fed)}"
+        loop.admit(sid)
+        fed[sid], wire[sid], quiet[sid] = [np.zeros(0, np.float32)], b"", 0
+        ramp[sid] = [float(rng.uniform(-5, 5)), float(rng.uniform(-.5, .5))] \
+            if rng.random() < 1 / 3 else None
+        live.append(sid)
+
+    def offer(sid, c):
+        if ramp[sid] is None:
+            y = _walk(rng, c)
+        else:
+            y0, a = ramp[sid]
+            y = (y0 + a * np.arange(c)).astype(np.float32)
+            ramp[sid][0] = y0 + a * c
+        assert loop.offer(sid, y) == c
+        fed[sid].append(y)
+
+    def collect(blobs):
+        for sid, _gen, blob in blobs:
+            wire[sid] += blob
+
     def close(sid):
-        rep = mgr.evict(sid)
+        rep = loop.evict(sid)   # drains the points still queued
         live.remove(sid)
-        wire[sid] = wire.get(sid, b"") + rep.tail
-        y = np.concatenate(fed[sid]) if fed[sid] else None
-        if y is not None and y.size:
-            ref = _offline_bytes(y, method, protocol, max_run=64)
+        collect(rep.wire)
+        wire[sid] += rep.tail
+        y = np.concatenate(fed[sid])
+        if y.size:
+            ref = _offline_parts(y, method, protocol, max_run=64)
             if protocol == "twostreams":
-                # the emitter interleaves the two wires per chunk, the
-                # offline encoder concatenates them whole — compare totals
-                assert len(wire[sid]) == len(ref), \
+                # the wire interleaves the two streams tick by tick; the
+                # archive keeps them apart, as the offline encoder does
+                held = store._streams[(sid, rep.slot, rep.generation)]
+                assert (bytes(held.payload), bytes(held.payload2)) == ref, \
                     (sid, rep.slot, rep.generation)
-            else:
-                assert wire[sid] == ref, (sid, rep.slot, rep.generation)
-            assert rep.nbytes == len(wire[sid])
+                ref = b"".join(ref)
+            assert wire[sid] == ref if protocol != "twostreams" \
+                else len(wire[sid]) == len(ref), \
+                (sid, rep.slot, rep.generation)
+            assert rep.points == y.size
+        assert rep.nbytes == len(wire[sid])
 
     for _ in range(n_ops):
         op = rng.integers(3)
         if op == 0 and len(live) < mgr.capacity:
-            sid = f"s{next_id}"
-            next_id += 1
-            mgr.admit(sid)
-            fed[sid] = []
-            live.append(sid)
+            admit()
         elif op == 1 and live:
             close(live[int(rng.integers(len(live)))])
         elif live:
-            n = int(rng.integers(1, 40))
-            plane = np.zeros((mgr.capacity, n), np.float32)
-            lengths = np.zeros(mgr.capacity, np.int64)
+            loop.tick_width = int(rng.integers(1, 40))
             for sid in live:
-                i = mgr._by_stream[sid]
-                c = int(rng.integers(0, n + 1))
-                if c:
-                    chunk = _walk(rng, c)
-                    plane[i, :c] = chunk
-                    lengths[i] = c
-                    fed[sid].append(chunk)
-            for sid2, _gen, blob in mgr.step(plane, lengths):
-                wire[sid2] = wire.get(sid2, b"") + blob
+                if quiet[sid]:
+                    quiet[sid] -= 1
+                elif rng.random() < 0.2:
+                    quiet[sid] = int(rng.integers(2, 6))
+                else:
+                    offer(sid, int(rng.integers(0, 2 * loop.tick_width)))
+            collect(loop.tick().wire)
+    # Slots recycle LIFO: an evict with points queued, then an admit,
+    # puts the new stream in the slot just freed.
+    if not live:
+        admit()
+    offer(live[0], 50)
+    close(live[0])
+    admit()
+    offer(live[-1], 50)
     for sid in list(live):
         close(sid)
-    # churn actually recycled slots
-    assert any(s.generation > 1 for s in mgr.slots) or n_ops < 12
+    assert any(s.generation > 1 for s in mgr.slots)
 
 
 def test_churn_fixed_draws():
@@ -121,9 +173,9 @@ def test_churn_fixed_draws():
         _churn_body(seed, 40)
 
 
-def test_churn_other_combinations():
-    _churn_body(3, 30, method="swing", protocol="twostreams")
-    _churn_body(4, 30, method="angle", protocol="singlestreamv")
+@pytest.mark.parametrize("method,protocol", CHURN_COMBOS)
+def test_churn_other_combinations(method, protocol):
+    _churn_body(3, 30, method=method, protocol=protocol)
 
 
 if HAVE_HYPOTHESIS:
@@ -402,63 +454,30 @@ def test_masked_pos_host_mirrors_device_pos():
 # Padded plane over a real 8-device mesh (subprocess)
 # ---------------------------------------------------------------------------
 
-def test_serving_churn_8_devices_subprocess():
-    script = r"""
-import os
+EIGHT_DEVICES = r"""
+import os, sys
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
-import numpy as np
 import jax
 assert jax.device_count() == 8, jax.devices()
-from repro.core.evaluate import BATCHED_SEGMENTERS
-from repro.core.protocol_engine import encode_batch
 from repro.serving import SlotManager
+sys.path.insert(0, "tests")
+from test_serving import _churn_body
 
-def offline(y):
-    yb = y[None].astype(np.float32)
-    seg = BATCHED_SEGMENTERS["linear"](yb, 0.4, max_run=64)
-    return encode_batch(seg, yb, "singlestream", "disjoint")[0]
-
-rng = np.random.default_rng(2)
-mgr = SlotManager("linear", capacity=12, eps0=0.4, max_run=64)
+mgr = SlotManager(sys.argv[1], sys.argv[2], capacity=12)
 assert mgr.capacity == 16 and mgr.rows_per_shard == 2   # padded to 8 devs
-fed, wire = {}, {}
-live = []
-for k in range(30):
-    op = rng.integers(3)
-    if op == 0 and len(live) < 12:
-        sid = f"s{k}"
-        mgr.admit(sid); fed[sid] = []; wire[sid] = b""; live.append(sid)
-    elif op == 1 and live:
-        sid = live.pop(int(rng.integers(len(live))))
-        wire[sid] += mgr.evict(sid).tail
-        y = np.concatenate(fed[sid]) if fed[sid] else np.zeros(0)
-        if y.size:
-            assert wire[sid] == offline(y), sid
-    elif live:
-        n = int(rng.integers(1, 48))
-        plane = np.zeros((mgr.capacity, n), np.float32)
-        lengths = np.zeros(mgr.capacity, np.int64)
-        for sid in live:
-            i = mgr._by_stream[sid]
-            c = int(rng.integers(0, n + 1))
-            if c:
-                chunk = np.cumsum(rng.normal(0, .6, c)).astype(np.float32)
-                plane[i, :c] = chunk; lengths[i] = c; fed[sid].append(chunk)
-        for sid, _g, blob in mgr.step(plane, lengths):
-            wire[sid] += blob
-for sid in list(live):
-    w = wire[sid] + mgr.evict(sid).tail
-    y = np.concatenate(fed[sid]) if fed[sid] else np.zeros(0)
-    if y.size:
-        assert w == offline(y), sid
+_churn_body(2, 30, sys.argv[1], sys.argv[2], capacity=12)
 print("SERVE8 OK")
 """
+
+
+@pytest.mark.parametrize("method,protocol", CHURN_COMBOS)
+def test_serving_churn_8_devices_subprocess(method, protocol):
     repo = os.path.join(os.path.dirname(__file__), "..")
     env = dict(os.environ, PYTHONPATH=os.path.join(repo, "src"),
                JAX_PLATFORMS="cpu")
-    out = subprocess.run([sys.executable, "-c", script],
-                         capture_output=True, text=True, timeout=560,
-                         env=env, cwd=repo)
+    out = subprocess.run([sys.executable, "-c", EIGHT_DEVICES, method,
+                          protocol], capture_output=True, text=True,
+                         timeout=560, env=env, cwd=repo)
     assert out.returncode == 0, out.stderr[-2000:]
     assert "SERVE8 OK" in out.stdout, out.stdout[-2000:]
 

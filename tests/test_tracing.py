@@ -175,6 +175,16 @@ def _serve(capacity, tick_width=16):
     return ServeLoop(mgr, tick_width=tick_width, queue_cap=256)
 
 
+def _events_between(y, lo, hi):
+    """Breaks the engine finalizes in stream ``y`` while its count of
+    points goes from ``lo`` to ``hi`` (a break at p comes with point
+    p + 1), read off the offline segmentation ``_serve`` matches."""
+    from repro.core.evaluate import BATCHED_SEGMENTERS
+    brk = np.asarray(BATCHED_SEGMENTERS["linear"](
+        y[None], 0.5, max_run=256).breaks)[0]
+    return int(brk[max(lo - 1, 0):hi - 1].sum())
+
+
 def test_tick_spans_counter_and_evict_drain(tmp_path):
     loop = _serve(8)
     for s in range(4):
@@ -213,6 +223,17 @@ def test_tick_spans_counter_and_evict_drain(tmp_path):
         assert in_tick[name] == 4, name
     assert in_tick["repro.serve.budget"] == 0
     assert _inside(spans, "repro.slots.step")["repro.slots.emit"] == 4
+    # Each shard's emitter call names the rows it packs (those with
+    # events) and the events: the traced tick's three streams, then the
+    # evict's three drain ticks of stream 1.
+    fed = [data[0, :18], np.r_[data[1, :19], data[1, 40:80]], data[2, :20]]
+    ev = [_events_between(fed[s], 8, 18 + s) for s in range(3)]
+    expect = [{"rows": sum(e > 0 for e in ev), "events": sum(ev)}]
+    for lo, hi in ((19, 35), (35, 51), (51, 59)):
+        e = _events_between(fed[1], lo, hi)
+        expect.append({"rows": int(e > 0), "events": e})
+    assert [s[3] for s in spans if s[0] == "repro.slots.emit"] == expect
+    assert expect[0]["rows"] == 3 and expect[0]["events"] > 3
 
     evicts = [s for s in spans if s[0] == "repro.serve.evict"]
     assert len(evicts) == 1 and evicts[0][3] == {"queued": 40}
