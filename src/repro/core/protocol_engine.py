@@ -1110,10 +1110,11 @@ class SlotPlaneEmitter(ProtocolEmitter):
     ``points[r]`` counts the values row ``r`` took since its reset.
 
     Values wait in a per-row ring, position ``p`` at ``p % W``.  No record
-    reaches further back from its own break than a ``max_run``-point
-    segment after a partial burst of ``burst_cap`` values, and a break
-    comes at most one plane behind the newest value, so ``W`` is
-    ``max_run + burst_cap`` plus the widest plane taken so far.
+    reaches back from its break past a ``max_run``-point segment and the
+    partial burst before it (under ``burst_cap`` values), and a plane of
+    width ``n`` finalizes breaks at most ``n + 1`` positions behind the
+    row's newest value, so ``W`` is ``max_run + burst_cap`` plus the
+    widest plane taken so far.
 
     ``reset_rows`` starts rows afresh (admission); ``flush_rows`` closes
     rows and returns their closing bytes (eviction).  Each row's bytes

@@ -251,10 +251,10 @@ def test_emitter_fused_packer_deferred_kinds_at_s64(method):
 
 
 class _OneRow:
-    """One stream's own one-row ``ProtocolEmitter``, fed as the serving
-    plane fed each slot before it had a plane emitter: a tick's events,
-    tagged with row-local positions, are scattered onto the columns
-    finalized since the last feed (the close comes with no values)."""
+    """One stream's own one-row ``ProtocolEmitter``, the reference for a
+    plane row: a tick's events, tagged with row-local positions, are
+    scattered onto the columns finalized since the last feed (the close
+    comes with no values)."""
 
     def __init__(self, protocol, knot_kind):
         self.em = ProtocolEmitter(protocol, 1, knot_kind=knot_kind)
