@@ -1,18 +1,14 @@
-"""Framework-side benchmarks: kernel throughput, gradient compression,
-PLA KV-cache compression."""
+"""Framework-side benchmarks: segmenter kernel throughput and the
+adaptive-ε regime change."""
 
 from __future__ import annotations
 
-import json
-import os
 import time
-from typing import Dict, List, Tuple
+from typing import List, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
-
-from .paper_eval import OUT_DIR
 
 
 def _time(fn, *args, iters=3) -> float:
@@ -50,86 +46,6 @@ def kernel_throughput() -> List[Tuple[str, float, str]]:
         us = _time(f, y)
         rows.append((f"jax_pla/reconstruct/{S}x{T}", us,
                      f"{S*T/us*1e6/1e6:.1f}Mpts/s"))
-    return rows
-
-
-def grad_compression_bench() -> List[Tuple[str, float, str]]:
-    """Wire-bytes ratio + error of PLA gradient compression on real
-    gradients from a small training run."""
-    from repro.compression.grad import (GradCompressionConfig,
-                                        compression_report)
-    from repro.data.pipeline import PipelineConfig, TokenPipeline
-    from repro.models.base import ModelConfig
-    from repro.models.zoo import build_model
-
-    cfg = ModelConfig(n_layers=2, d_model=128, n_heads=8, n_kv_heads=2,
-                      d_ff=512, vocab=1024)
-    api = build_model(cfg)
-    params = api.init(jax.random.PRNGKey(0))
-    pipe = TokenPipeline(PipelineConfig(vocab=1024, global_batch=8,
-                                        seq_len=128))
-    grads = jax.grad(api.loss)(params, pipe.batch_at(0))
-    rows = []
-    for method in ("angle", "linear"):
-        gcfg = GradCompressionConfig(method=method, k_max=32, eps_rel=0.05)
-        t0 = time.perf_counter()
-        rep = compression_report(grads, gcfg)
-        dt = (time.perf_counter() - t0) * 1e6
-        raw = sum(r["raw_bytes"] for r in rep.values())
-        wire = sum(r.get("fixed_wire_bytes", r["raw_bytes"])
-                   for r in rep.values())
-        proto = sum(r.get("protocol_bytes", r["raw_bytes"])
-                    for r in rep.values())
-        rows.append((f"grad_compress/{method}", dt,
-                     f"fixed={wire/raw:.3f}x proto={proto/raw:.3f}x"))
-    with open(os.path.join(OUT_DIR, "grad_compress.json"), "w") as f:
-        json.dump({r[0]: r[2] for r in rows}, f, indent=2)
-    return rows
-
-
-def kv_cache_bench() -> List[Tuple[str, float, str]]:
-    """PLA KV-block compression on K/V tensors from a real forward pass +
-    the induced attention-output perturbation.
-
-    Keys are compressed PRE-RoPE (the rotary phase makes post-RoPE keys
-    oscillate along time and kills compressibility); the rotation is
-    re-applied after reconstruction, exactly as decode would.
-    """
-    from repro.compression.kv_cache import PLAKVConfig, \
-        compress_kv_block, decompress_kv_block, kv_compression_stats
-    from repro.models.base import ModelConfig
-    from repro.models.flash import flash_attention
-    from repro.models.layers import apply_rope, init_attention
-    cfg = ModelConfig(d_model=128, n_heads=8, n_kv_heads=2, head_dim=16,
-                      dtype="float32")
-    key = jax.random.PRNGKey(0)
-    p = init_attention(key, cfg)
-    # Smooth-ish hidden states (residual stream is autocorrelated in
-    # practice; iid would be the adversarial case).
-    x = jnp.cumsum(0.2 * jax.random.normal(key, (2, 256, 128)), axis=1)
-    pos = jnp.broadcast_to(jnp.arange(256, dtype=jnp.int32), (2, 256))
-    q = jnp.einsum("btd,dhk->bthk", x, p["wq"])
-    k_pre = jnp.einsum("btd,dhk->bthk", x, p["wk"])
-    v = jnp.einsum("btd,dhk->bthk", x, p["wv"])
-    qr = apply_rope(q, pos, cfg.rope_theta)
-    out_ref = flash_attention(qr, apply_rope(k_pre, pos, cfg.rope_theta),
-                              v, True, None, 256, 256)
-    rows = []
-    for eps in (0.01, 0.05, 0.2):
-        kcfg = PLAKVConfig(eps=eps, block=256, k_max=64)
-        st = kv_compression_stats(k_pre, v, kcfg)
-        blk = compress_kv_block(k_pre, v, kcfg)
-        kd, vd = decompress_kv_block(blk, kcfg)
-        out_pla = flash_attention(
-            qr, apply_rope(kd.astype(x.dtype), pos, cfg.rope_theta),
-            vd.astype(x.dtype), True, None, 256, 256)
-        dout = float(jnp.abs(out_pla - out_ref).max())
-        rows.append((f"kv_cache/eps={eps}", 0.0,
-                     f"ratio={st['ratio']:.3f} kerr={st['k_max_err']:.3g} "
-                     f"overflow={st['k_overflow_rows']}+"
-                     f"{st['v_overflow_rows']} attn_out_err={dout:.3g}"))
-    with open(os.path.join(OUT_DIR, "kv_cache.json"), "w") as f:
-        json.dump({r[0]: r[2] for r in rows}, f, indent=2)
     return rows
 
 

@@ -32,8 +32,6 @@ def main() -> None:
     csv_rows.extend(framework_bench.kernel_throughput())
     csv_rows.extend(streaming_bench.streaming_bench())  # -> BENCH_streaming.json
     csv_rows.extend(protocol_bench.protocol_bench())    # -> BENCH_protocols.json
-    csv_rows.extend(framework_bench.grad_compression_bench())
-    csv_rows.extend(framework_bench.kv_cache_bench())
     csv_rows.extend(framework_bench.adaptive_eps_bench())
     # Under this aggregator jax initialized long ago, so the fleet bench's
     # 8-fake-device XLA flag can't apply — the scaling sweep degrades to

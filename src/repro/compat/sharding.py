@@ -1,8 +1,6 @@
 """Sharding API in one place: mesh context, axis types, shard_map.
 
 - :data:`AxisType` — ``jax.sharding.AxisType``;
-- :func:`get_abstract_mesh` — a normalized :class:`MeshInfo` view of the
-  active mesh (``None`` when no mesh is active);
 - :func:`make_mesh` — ``jax.make_mesh`` with all-Auto axis types by
   default;
 - :func:`use_mesh` — ``jax.set_mesh`` (``None`` -> no-op context);
@@ -12,42 +10,11 @@
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 from typing import Any, Dict, Iterable, Optional, Sequence, Tuple
 
 import jax
 
 AxisType = jax.sharding.AxisType
-
-
-@dataclasses.dataclass(frozen=True)
-class MeshInfo:
-    """View of the active (abstract) mesh.
-
-    ``shape`` maps axis name -> size in mesh order; ``axis_types`` aligns
-    with ``shape.items()``.  Matches the parts of ``AbstractMesh`` that the
-    model layer consumes (``repro.models.base.shard``).
-    """
-    shape: Dict[str, int]
-    axis_types: Tuple[Any, ...]
-
-    @property
-    def axis_names(self) -> Tuple[str, ...]:
-        return tuple(self.shape)
-
-
-def get_abstract_mesh() -> Optional[MeshInfo]:
-    """The active mesh as :class:`MeshInfo`, or ``None`` when there is none
-    (the ``jax.set_mesh`` context)."""
-    m = jax.sharding.get_abstract_mesh()
-    if m is None or not m.shape:
-        return None
-    return MeshInfo(dict(m.shape), tuple(m.axis_types))
-
-
-def axis_size(axis_name: str) -> int:
-    """Static size of a bound mesh axis (inside shard_map / collectives)."""
-    return jax.lax.axis_size(axis_name)
 
 
 def auto_axis_types(n: int) -> Tuple[Any, ...]:

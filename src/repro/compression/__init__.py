@@ -1,27 +1,9 @@
-"""PLA stream compression as first-class framework features.
+"""PLA stream compression of host-side metric streams.
 
-- :mod:`grad`      — error-feedback PLA-compressed cross-pod gradient
-  reduction (paper scenario 1: fewer bytes over the slow link).
-- :mod:`kv_cache`  — eps-bounded PLA compression of cold KV-cache blocks
-  (paper scenario 2: datacenter storage reduction).
 - :mod:`telemetry` — host-side metric streams compressed with the paper's
   lowest-latency protocol (SingleStreamV).
-- :mod:`ckpt`      — byte-level PLA compression of smooth checkpoint
-  tensors (optimizer second moments, EMAs).
 """
 
-from .grad import (GradCompressionConfig, init_error_feedback,
-                   pla_compress_leaf, pla_decompress_leaf,
-                   pod_compressed_mean, compression_report)
-from .kv_cache import PLAKVConfig, compress_kv_block, decompress_kv_block, \
-    kv_compression_stats
 from .telemetry import TelemetryCompressor
-from .ckpt import encode_array, decode_array
 
-__all__ = [
-    "GradCompressionConfig", "init_error_feedback", "pla_compress_leaf",
-    "pla_decompress_leaf", "pod_compressed_mean", "compression_report",
-    "PLAKVConfig", "compress_kv_block", "decompress_kv_block",
-    "kv_compression_stats", "TelemetryCompressor", "encode_array",
-    "decode_array",
-]
+__all__ = ["TelemetryCompressor"]

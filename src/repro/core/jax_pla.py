@@ -24,8 +24,8 @@ segment one knot in the past, so their scan emits position-tagged events
 ``(ev, pos, a, v)`` that the wrappers scatter into the canonical event
 arrays, and their chunked output has data-dependent width (below).
 
-All take ``y: (S, T)`` on the regular grid ``t = 0..T-1`` (the framework's
-streams — gradient rows, KV-cache channels, telemetry — are index-stamped)
+All take ``y: (S, T)`` on the regular grid ``t = 0..T-1`` (the fleet's
+sensor streams and host telemetry are index-stamped)
 and return dense, shape-static output:
 
 - ``breaks: (S, T) bool`` — True where a segment *ends* (last covered t)
@@ -52,8 +52,8 @@ size with output **bit-identical** to the one-shot offline call.
 - :func:`flush` — close the trailing run: emits the single final event
   column (a forced break at the last consumed position) and resets the
   carry, so the next :func:`step_chunk` starts a fresh stream at the next
-  absolute position (used by the adaptive-ε controller's retune boundaries
-  and the KV block boundaries).
+  absolute position (used by the adaptive-ε controller's retune
+  boundaries).
 
 Concatenating all :func:`step_chunk` outputs plus the :func:`flush` column
 reproduces the offline ``(S, T)`` :class:`SegmentOutput` exactly.  Offline
@@ -82,12 +82,7 @@ limit.
 
 :func:`propagate_lines` turns segments into per-point reconstruction;
 :func:`to_records` / :func:`decode_records` give the fixed-slot record form
-used by the compressed collectives, with SingleStream byte accounting.
-Records can also be built *incrementally*: :func:`records_init` allocates
-an empty fixed-slot buffer, :func:`records_append` scatters a chunk's
-events into the next free slots, and :func:`records_finalize` applies the
-same forward-fill padding / overflow marking as :func:`to_records` — the
-incremental path is bit-identical to the batch one.
+used by the tests as a reference record layout.
 All internal line state is likewise anchored at the current run's start, so
 t enters only through differences bounded by the run cap.
 """
@@ -112,9 +107,8 @@ __all__ = [
     "MaskedEvents", "MaskedSegmenterState", "masked_init_state",
     "masked_step_chunk", "masked_flush_rows", "masked_set_eps",
     "propagate_lines", "to_records", "decode_records", "records_to_events",
-    "records_init", "records_append", "records_finalize",
     "scatter_events", "release_deferred", "assemble_deferred_events",
-    "singlestream_nbytes", "PLARecords",
+    "PLARecords",
 ]
 
 _BIG = jnp.float32(3.4e38)
@@ -2105,12 +2099,7 @@ class PLARecords(NamedTuple):
     ``y(t) = v[k] + a[k] * (t - seg_end[k])``.  ``count`` = true number of
     segments; ``overflow`` = row had more than K segments (its tail is
     covered by extending slot K-1's line — callers relying on the eps
-    guarantee must check/react, e.g. error feedback or eps escalation).
-
-    During *incremental* building (:func:`records_init` /
-    :func:`records_append`) ``count`` holds the uncapped running total and
-    ``overflow`` stays False; :func:`records_finalize` converts to the
-    canonical (capped, padded, overflow-marked) form above.
+    guarantee must check/react).
     """
 
     seg_end: jax.Array  # (S, K) int32
@@ -2121,8 +2110,8 @@ class PLARecords(NamedTuple):
 
 
 def _records_pad(idx, ak, vk, count, k_max, t_len):
-    """Canonical padding shared by to_records / records_finalize: slots past
-    the last real segment repeat it; overflow rows pin slot K-1 to t-1."""
+    """Canonical padding of :func:`to_records`: slots past the last real
+    segment repeat it; overflow rows pin slot K-1 to t-1."""
     kk = jnp.arange(k_max)[None, :]
     last = jnp.clip(count - 1, 0, k_max - 1)[:, None]
     src = jnp.minimum(kk, last).astype(jnp.int32)
@@ -2149,61 +2138,6 @@ def to_records(seg: SegmentOutput, k_max: int) -> PLARecords:
     return _records_pad(idx, ak, vk, count, k_max, T)
 
 
-def records_init(n_streams: int, k_max: int, dtype=jnp.float32) -> PLARecords:
-    """Empty fixed-slot buffer for incremental record emission."""
-    return PLARecords(jnp.zeros((n_streams, k_max), jnp.int32),
-                      jnp.zeros((n_streams, k_max), dtype),
-                      jnp.zeros((n_streams, k_max), dtype),
-                      jnp.zeros((n_streams,), jnp.int32),
-                      jnp.zeros((n_streams,), bool))
-
-
-@jax.jit
-def records_append(rec: PLARecords, seg_chunk: SegmentOutput,
-                   t_offset) -> PLARecords:
-    """Scatter a chunk's break events into the next free record slots.
-
-    ``seg_chunk`` covers absolute positions ``[t_offset, t_offset + n)``
-    (e.g. the output of :func:`step_chunk` at ``t_offset = state.emitted``
-    taken *before* the call).  Events beyond ``k_max`` slots are dropped but
-    still counted, so :func:`records_finalize` marks the row overflowed —
-    exactly like the batch :func:`to_records`."""
-    brk, a, v = seg_chunk
-    S, n = a.shape
-    K = rec.seg_end.shape[1]
-    if n == 0:
-        return rec
-    kc = min(n, K)  # at most K new events can land in slots; rest overflow
-    new = brk.sum(axis=1).astype(jnp.int32)
-
-    def row(brk_r, a_r, v_r):
-        idx = jnp.nonzero(brk_r, size=kc, fill_value=0)[0].astype(jnp.int32)
-        return idx, a_r[idx], v_r[idx]
-
-    idx, ak, vk = jax.vmap(row)(brk, a, v)
-    j = jnp.arange(kc)[None, :]
-    slots = rec.count[:, None] + j
-    # invalid or overflowing events -> slot K, dropped by mode="drop"
-    slots = jnp.where((j < new[:, None]) & (slots < K), slots, K)
-    rows = jnp.arange(S)[:, None]
-    t_offset = jnp.asarray(t_offset, jnp.int32)
-    seg_end = rec.seg_end.at[rows, slots].set(t_offset + idx, mode="drop")
-    a2 = rec.a.at[rows, slots].set(ak, mode="drop")
-    v2 = rec.v.at[rows, slots].set(vk, mode="drop")
-    return PLARecords(seg_end, a2, v2, rec.count + new, rec.overflow)
-
-
-@functools.partial(jax.jit, static_argnames=("t_len",))
-def records_finalize(rec: PLARecords, t_len: int) -> PLARecords:
-    """Convert an incrementally built buffer to canonical padded form.
-
-    Bit-identical to ``to_records(seg, k_max)`` when the appended chunks
-    concatenate to ``seg`` (requires >= 1 event per row, which the
-    streaming flush guarantees)."""
-    return _records_pad(rec.seg_end, rec.a, rec.v, rec.count,
-                        rec.seg_end.shape[1], t_len)
-
-
 @functools.partial(jax.jit, static_argnames=("t_len",))
 def records_to_events(rec: PLARecords, t_len: int) -> SegmentOutput:
     """Expand canonical fixed-slot records back to (S, T) event arrays.
@@ -2211,9 +2145,9 @@ def records_to_events(rec: PLARecords, t_len: int) -> SegmentOutput:
     The inverse of :func:`to_records` for non-overflowed rows: each valid
     slot scatters a break (and its anchored line) at ``seg_end``.  The
     result feeds the event-form consumers — the Pallas reconstruction
-    kernel and the protocol engine — so record buffers (e.g. compressed
-    KV blocks, gradient records) can go through the same vectorized
-    protocol/metrics/reconstruction paths as fresh segmentations.
+    kernel and the protocol engine — so record buffers can go through the
+    same vectorized protocol/metrics/reconstruction paths as fresh
+    segmentations.
     Overflowed rows reconstruct their covered prefix exactly; the tail
     extends slot K-1's line (same contract as :func:`decode_records`).
     """
@@ -2258,24 +2192,3 @@ def decode_records(rec: PLARecords, t_len: int) -> jax.Array:
 
     return jax.vmap(row)(rec.seg_end, rec.a, rec.v)
 
-
-def singlestream_nbytes(rec: PLARecords, t_len: int,
-                        value_bytes: int = 4, counter_bytes: int = 1
-                        ) -> jax.Array:
-    """Per-row SingleStream wire size (paper §5.2.2) for this segmentation.
-
-    Segments of >= 3 points cost ``counter + 2 * value`` bytes; shorter
-    segments flush as singletons at ``counter + value`` bytes each.
-    """
-    seg_end, a, v, count, _ = rec
-    S, K = seg_end.shape
-    prev_end = jnp.concatenate(
-        [jnp.full((S, 1), -1, seg_end.dtype), seg_end[:, :-1]], axis=1)
-    lengths = seg_end - prev_end
-    valid = jnp.arange(K)[None, :] < count[:, None]
-    lengths = jnp.where(valid, lengths, 0)
-    is_seg = lengths >= 3
-    seg_cost = counter_bytes + 2 * value_bytes
-    single_cost = counter_bytes + value_bytes
-    return (is_seg * seg_cost
-            + (~is_seg) * lengths * single_cost).sum(axis=1)

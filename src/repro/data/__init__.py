@@ -1,4 +1,3 @@
 from .synthetic import make_dataset, DATASETS
-from .pipeline import TokenPipeline, PipelineConfig
 
-__all__ = ["make_dataset", "DATASETS", "TokenPipeline", "PipelineConfig"]
+__all__ = ["make_dataset", "DATASETS"]

@@ -1,9 +1,7 @@
-from .partition import param_specs, batch_specs, spec_for_leaf
 from .fleet import (FLEET_AXIS, FleetPointMetrics, FleetStream, fleet_mesh,
                     fleet_shard, fleet_point_metrics, fleet_encode,
                     pad_to_mesh)
 
-__all__ = ["param_specs", "batch_specs", "spec_for_leaf",
-           "FLEET_AXIS", "FleetPointMetrics", "FleetStream", "fleet_mesh",
+__all__ = ["FLEET_AXIS", "FleetPointMetrics", "FleetStream", "fleet_mesh",
            "fleet_shard", "fleet_point_metrics", "fleet_encode",
            "pad_to_mesh"]

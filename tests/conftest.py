@@ -1,6 +1,6 @@
-# NOTE (per the brief): no XLA_FLAGS / device-count overrides here — smoke
-# tests and benches must see the real (1-device) CPU.  Only the dry-run
-# launcher sets xla_force_host_platform_device_count, in its own process.
+# No XLA_FLAGS / device-count overrides here: tests must see the real
+# (1-device) CPU.  Multi-device tests set xla_force_host_platform_device_count
+# in a subprocess of their own.
 
 
 import jax

@@ -51,44 +51,6 @@ def test_axis_type_has_expected_members():
     assert cs.AxisType is jax.sharding.AxisType
 
 
-def test_get_abstract_mesh_none_without_mesh():
-    assert cs.get_abstract_mesh() is None
-
-
-def test_get_abstract_mesh_inside_context():
-    mesh = cs.make_mesh((1,), ("data",))
-    with cs.use_mesh(mesh):
-        info = cs.get_abstract_mesh()
-        assert info is not None
-        assert info.shape == {"data": 1}
-        assert info.axis_names == ("data",)
-        assert info.axis_types == (cs.AxisType.Auto,)
-    assert cs.get_abstract_mesh() is None
-
-
-def test_get_abstract_mesh_via_new_spelling(monkeypatch):
-    """New-JAX path: jax.sharding.get_abstract_mesh() is used when present."""
-    class _AbstractMesh:
-        shape = {"data": 2, "model": 4}
-        axis_types = (cs.AxisType.Auto, cs.AxisType.Manual)
-
-    monkeypatch.setattr(jax.sharding, "get_abstract_mesh",
-                        lambda: _AbstractMesh(), raising=False)
-    info = cs.get_abstract_mesh()
-    assert info.shape == {"data": 2, "model": 4}
-    assert info.axis_types == (cs.AxisType.Auto, cs.AxisType.Manual)
-
-
-def test_get_abstract_mesh_new_spelling_empty(monkeypatch):
-    class _Empty:
-        shape = {}
-        axis_types = ()
-
-    monkeypatch.setattr(jax.sharding, "get_abstract_mesh",
-                        lambda: _Empty(), raising=False)
-    assert cs.get_abstract_mesh() is None
-
-
 def test_make_mesh_forwards_axis_types_when_supported(monkeypatch):
     seen = {}
 
@@ -128,7 +90,7 @@ def test_axis_size_inside_shard_map():
     sizes = []
 
     def f(x):
-        sizes.append(cs.axis_size("pod"))
+        sizes.append(jax.lax.axis_size("pod"))
         return x
 
     cs.shard_map(f, mesh=mesh, in_specs=P("pod"), out_specs=P("pod"),
@@ -219,7 +181,7 @@ def test_no_direct_version_dependent_refs_outside_compat():
     root = pathlib.Path(__file__).resolve().parents[1] / "src" / "repro"
     pat = re.compile(
         r"pltpu\.(TPU)?CompilerParams"
-        r"|jax\.sharding\.(get_abstract_mesh|AxisType)"
+        r"|jax\.sharding\.AxisType"
         r"|jax\.(set_mesh|shard_map)\b"
         r"|jax\.make_mesh\(")
     offenders = []

@@ -1,129 +1,9 @@
-"""Framework compression features: gradient EF loop, KV cache, telemetry,
-checkpoint codec, and the shard_map cross-pod reduction."""
+"""Telemetry compression: the SingleStreamV sender for host metric
+streams."""
 
 import numpy as np
-import jax
-import jax.numpy as jnp
-import pytest
 
-from repro.compression.ckpt import decode_array, encode_array
-from repro.compression.grad import (GradCompressionConfig,
-                                    init_error_feedback, pla_compress_leaf,
-                                    pla_decompress_leaf)
-from repro.compression.kv_cache import (PLAKVConfig, compress_kv_block,
-                                        decompress_kv_block,
-                                        kv_compression_stats)
 from repro.compression.telemetry import TelemetryCompressor
-
-
-def test_grad_compression_error_bounded_by_ladder():
-    rng = np.random.default_rng(0)
-    g = jnp.asarray(rng.normal(0, 0.01, (128, 256)), jnp.float32)
-    cfg = GradCompressionConfig(k_max=48, eps_rel=0.05)
-    rec, eps = pla_compress_leaf(g, cfg)
-    dec = pla_decompress_leaf(rec, g.shape, cfg)
-    err_rows = np.abs(np.asarray(dec - g)).max(axis=1)
-    # bounded by per-row eps + fp16 wire quantization slack
-    eps_rows = np.asarray(eps)
-    assert int(rec.overflow.sum()) == 0
-    slack = 6e-3 * np.abs(np.asarray(g)).max() + 1e-5
-    assert (err_rows <= eps_rows * 1.05 + slack).all()
-
-
-def test_grad_compression_reduces_bytes_on_smooth_grads():
-    rng = np.random.default_rng(1)
-    smooth = jnp.asarray(
-        np.cumsum(rng.normal(0, 1e-3, (64, 256)), axis=1), jnp.float32)
-    cfg = GradCompressionConfig(k_max=32, eps_rel=0.05)
-    rec, _ = pla_compress_leaf(smooth, cfg)
-    wire = rec.seg_end.size + 2 * rec.a.size + 2 * rec.v.size
-    assert wire < 0.25 * smooth.size * 4
-
-
-def test_error_feedback_converges_unbiased():
-    """EF compressed mean: accumulated residual stays bounded and the
-    time-average of decoded gradients matches the true gradient."""
-    rng = np.random.default_rng(2)
-    true_g = jnp.asarray(rng.normal(0, 0.01, (32, 256)), jnp.float32)
-    from repro.compression.grad import apply_escape, overflow_escape_rows
-    from repro.core.jax_pla import PLARecords, decode_records
-    cfg = GradCompressionConfig(k_max=8, eps_rel=0.5)  # aggressive
-    ef = jnp.zeros_like(true_g)
-    decoded_sum = jnp.zeros_like(true_g)
-    # eps anchored to the raw-gradient scale, as pod_compressed_mean does.
-    eps_rows = cfg.eps_rel * jnp.sqrt(jnp.mean(true_g ** 2, axis=1) + 1e-20)
-    n = 30
-    for _ in range(n):
-        rec, _ = pla_compress_leaf(true_g + ef, cfg, eps_rows=eps_rows)
-        rec32 = PLARecords(rec.seg_end.astype(jnp.int32),
-                           rec.a.astype(jnp.float32),
-                           rec.v.astype(jnp.float32),
-                           rec.count.astype(jnp.int32), rec.overflow)
-        esc = overflow_escape_rows(true_g + ef, rec, cfg)
-        dec = apply_escape(decode_records(rec32, cfg.chunk), rec, esc)
-        dec = dec.reshape(true_g.shape)
-        ef = (true_g + ef) - dec
-        decoded_sum += dec
-    # Telescoping: sum(dec_i) = n*g + ef_0 - ef_n, so the time-averaged
-    # decoded gradient deviates by exactly |ef_n|/n <= eps_max/n.
-    eps_max = float(eps_rows.max()) * 4.0 ** (cfg.eps_ladder - 1)
-    avg_err = float(jnp.abs(decoded_sum / n - true_g).max())
-    assert avg_err <= eps_max / n * 1.2 + 1e-6  # EF bias ~ 1/n
-    assert float(jnp.abs(ef).max()) <= eps_max * 1.2  # residual bounded
-
-
-def test_pod_compressed_mean_under_shard_map():
-    """The cross-pod compressed reduction agrees across pods and stays
-    close to the exact mean (within eps + EF residual)."""
-    if len(jax.devices()) < 2:
-        pytest.skip("needs >= 2 devices (set "
-                    "XLA_FLAGS=--xla_force_host_platform_device_count=2)")
-    from jax.sharding import PartitionSpec as P
-    from repro.compat import sharding as compat_sharding
-    from repro.compression.grad import pod_compressed_mean
-    mesh = compat_sharding.make_mesh((2,), ("pod",))
-    cfg = GradCompressionConfig(k_max=64, eps_rel=0.05, min_leaf_size=128)
-    rng = np.random.default_rng(3)
-    g_all = jnp.asarray(np.cumsum(rng.normal(0, 0.01, (2, 16, 256)), 2),
-                        jnp.float32)
-    ef = jnp.zeros((2, 16, 256), jnp.float32)
-
-    def f(g, e):
-        mean, new_ef, stats = pod_compressed_mean(
-            {"w": g[0]}, {"w": e[0]}, cfg)
-        return mean["w"], new_ef["w"], stats["wire_bytes"].reshape(1)
-
-    fn = compat_sharding.shard_map(
-        f, mesh=mesh, in_specs=(P("pod"), P("pod")),
-        out_specs=(P("pod"), P("pod"), P("pod")),
-        axis_names={"pod"}, check=False)
-    with compat_sharding.use_mesh(mesh):
-        mean, new_ef, wire = jax.jit(fn)(g_all, ef)
-    mean = np.asarray(mean).reshape(2, 16, 256)
-    # both pods computed the same mean
-    np.testing.assert_allclose(mean[0], mean[1], rtol=0, atol=1e-6)
-    # close to the exact mean within eps-ish tolerance
-    exact = np.asarray(g_all).mean(axis=0)
-    scale = np.abs(exact).max()
-    assert np.abs(mean[0] - exact).max() <= 0.3 * scale
-    assert float(np.asarray(wire).sum()) > 0
-
-
-def test_kv_roundtrip_eps_and_escape():
-    rng = np.random.default_rng(4)
-    k = jnp.asarray(np.cumsum(rng.normal(0, 0.05, (2, 256, 2, 16)), 1),
-                    jnp.float32)
-    v = jnp.asarray(np.cumsum(rng.normal(0, 0.05, (2, 256, 2, 16)), 1),
-                    jnp.float32)
-    cfg = PLAKVConfig(eps=0.05, k_max=48)
-    blk = compress_kv_block(k, v, cfg)
-    kd, vd = decompress_kv_block(blk, cfg)
-    # overflow rows fall back to raw; everything obeys eps + fp16 slack
-    slack = 6e-3 * float(jnp.abs(k).max()) + 1e-4
-    assert float(jnp.abs(kd - k).max()) <= cfg.eps + slack
-    assert float(jnp.abs(vd - v).max()) <= cfg.eps + slack
-    st = kv_compression_stats(k, v, cfg)
-    assert st["compressed_bytes"] <= st["raw_bytes"] * 1.1
 
 
 def test_telemetry_eps_and_flush():
@@ -173,14 +53,3 @@ def test_telemetry_deferred_methods_stream_lag_aware():
         st, out_f = jax_pla.flush(st)
         wire += em.step_chunk(out_f)[0] + em.flush()[0]
         assert blobs[0] == wire, method
-
-
-def test_ckpt_codec_roundtrip_shapes_dtypes():
-    rng = np.random.default_rng(6)
-    for shape in ((100,), (33, 57), (4, 5, 6)):
-        x = np.cumsum(rng.normal(0, 1e-3, int(np.prod(shape)))) \
-            .reshape(shape).astype(np.float32)
-        blob = encode_array(x, eps_rel=1e-3)
-        y, eps = decode_array(blob)
-        assert y.shape == x.shape
-        assert np.abs(y - x).max() <= eps * 1.01 + 1e-9

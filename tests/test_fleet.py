@@ -29,14 +29,15 @@ except ImportError:          # fixed-draw twin below still runs
 import jax
 
 from repro.core import jax_pla
-from repro.core.evaluate import BATCHED_SEGMENTERS, METHOD_KNOT_KINDS
+from repro.core.evaluate import (BATCHED_SEGMENTERS, COMBINATIONS,
+                                 METHOD_KNOT_KINDS)
 from repro.core.protocol_engine import batched_point_metrics, encode_batch
 from repro.core.protocols import PROTOCOL_CAPS
 from repro.sharding.fleet import (FleetStream, fleet_encode, fleet_mesh,
                                   fleet_point_metrics, fleet_shard)
 
-COMBOS = [("angle", "singlestream"), ("linear", "singlestreamv"),
-          ("swing", "implicit"), ("mixed", "implicit")]
+# All 13 Table-2 combinations as (method, protocol) pairs.
+COMBOS = [COMBINATIONS[k] for k in sorted(COMBINATIONS)]
 
 
 def _batch(seed=0, S=8, T=220):
@@ -65,32 +66,13 @@ def test_fleet_metrics_bit_equal_to_batched(method, protocol):
     assert fleet_encode(fm, y) == encode_batch(seg, y, protocol, kk)
 
 
-def test_fleet_stream_chunked_bit_identical():
-    y = _batch(seed=4, S=4, T=150)
-    for method, protocol in (("angle", "singlestreamv"),
-                             ("swing", "implicit"),
-                             ("continuous", "implicit")):
-        cap = PROTOCOL_CAPS[protocol] or 256
-        kk = METHOD_KNOT_KINDS.get(method, "disjoint")
-        fs = FleetStream(method, protocol, 4, 0.8, block_s=8, block_t=32)
-        got = [b""] * 4
-        for lo in (0, 50, 100):
-            for s, b in enumerate(fs.push(y[:, lo:lo + 50])):
-                got[s] += b
-        for s, b in enumerate(fs.finish()):
-            got[s] += b
-        off = encode_batch(BATCHED_SEGMENTERS[method](y, 0.8, max_run=cap),
-                           y, protocol, kk)
-        assert got == off, (method, protocol)
-        assert fs.total_bytes == sum(len(b) for b in got)
-
-
 # ---------------------------------------------------------------------------
-# Row-block packing: several emitters per shard give the one emitter's bytes
+# Chunked pushes and row-block packing give the offline encode's bytes
 # ---------------------------------------------------------------------------
 
 def _fleet_bytes(fs, y, widths):
-    """Every stream's bytes over pushes of ``widths`` and the finish."""
+    """Every stream's bytes over pushes of ``widths`` and the finish
+    (twostreams: the ``(segment, singleton)`` pair)."""
     outs, pos = [], 0
     for w in widths:
         outs.append(fs.push(y[:, pos:pos + w]))
@@ -102,11 +84,37 @@ def _fleet_bytes(fs, y, widths):
     return [b"".join(parts) for parts in zip(*outs)]
 
 
+def _as_fleet_bytes(off, protocol):
+    """``encode_batch`` output in :func:`_fleet_bytes` form."""
+    return [tuple(b) if protocol == "twostreams" else b for b in off]
+
+
+def _total_len(got, protocol):
+    return sum(len(b"".join(b)) if protocol == "twostreams" else len(b)
+               for b in got)
+
+
+@pytest.mark.parametrize("method,protocol", COMBOS)
+def test_fleet_stream_chunked_bit_identical(method, protocol):
+    y = _batch(seed=4, S=4, T=150)
+    cap = PROTOCOL_CAPS[protocol] or 256
+    kk = METHOD_KNOT_KINDS.get(method, "disjoint")
+    fs = FleetStream(method, protocol, 4, 0.8, block_s=8, block_t=32)
+    got = _fleet_bytes(fs, y, (50, 50, 50))
+    off = encode_batch(BATCHED_SEGMENTERS[method](y, 0.8, max_run=cap),
+                       y, protocol, kk)
+    assert got == _as_fleet_bytes(off, protocol), (method, protocol)
+    assert fs.total_bytes == _total_len(got, protocol)
+
+
 @pytest.mark.parametrize("method,protocol,S,min_rows,blocks", [
     ("linear", "singlestream", 16, 8, 2),
     ("angle", "singlestreamv", 24, 8, 3),
     ("continuous", "implicit", 32, 8, 4),
     ("linear", "twostreams", 30, 10, 3),
+    ("swing", "implicit", 16, 8, 2),
+    ("disjoint", "singlestream", 24, 8, 3),
+    ("mixed", "implicit", 32, 8, 4),
 ])
 def test_fleet_row_blocks_bytes_equal_one_block(monkeypatch, method,
                                                 protocol, S, min_rows,
@@ -132,11 +140,8 @@ def test_fleet_row_blocks_bytes_equal_one_block(monkeypatch, method,
     kk = METHOD_KNOT_KINDS.get(method, "disjoint")
     off = encode_batch(BATCHED_SEGMENTERS[method](y, eps, max_run=cap), y,
                        protocol, kk)
-    assert got == [tuple(b) if protocol == "twostreams" else b
-                   for b in off]
-    assert fs.total_bytes == one.total_bytes == sum(
-        len(b"".join(b)) if protocol == "twostreams" else len(b)
-        for b in got)
+    assert got == _as_fleet_bytes(off, protocol)
+    assert fs.total_bytes == one.total_bytes == _total_len(got, protocol)
     assert store.keys() == one_store.keys()
     for k in store.keys():
         mine, ref = store._streams[k], one_store._streams[k]
@@ -206,7 +211,7 @@ def test_fleet_shape_and_mesh_errors():
 def test_fleet_sharded_8_devices_subprocess():
     """Bit-equality of the sharded pipeline under a real 8-device mesh
     (host-platform devices; XLA_FLAGS must precede jax init, hence the
-    subprocess — same pattern as test_runtime's multipod test)."""
+    subprocess)."""
     script = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"

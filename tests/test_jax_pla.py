@@ -9,7 +9,7 @@ from repro.core.jax_pla import (angle_segment, continuous_segment,
                                 disjoint_segment, linear_segment,
                                 mixed_segment, swing_segment,
                                 propagate_lines, to_records,
-                                decode_records, singlestream_nbytes)
+                                decode_records)
 from repro.core.methods import (run_angle, run_continuous, run_disjoint,
                                 run_linear, run_mixed, run_swing)
 
@@ -71,21 +71,6 @@ def test_records_roundtrip_and_overflow():
                                    rtol=1e-5, atol=1e-5)
     # Overflow rows still produce finite output (tail extension).
     assert np.isfinite(np.asarray(dec)).all()
-
-
-def test_singlestream_byte_accounting_matches_core():
-    """jax-side SingleStream byte accounting == paper protocol accounting."""
-    from repro.core import METHODS, PROTOCOLS
-    y = _streams(seed=3, S=4, T=200)
-    ts = np.arange(200, dtype=float)
-    seg = disjoint_segment(jnp.asarray(y, jnp.float64), 1.0, max_run=256)
-    rec = to_records(seg, k_max=128)
-    nbytes = singlestream_nbytes(rec, 200, value_bytes=8, counter_bytes=1)
-    for s in range(4):
-        out = METHODS["disjoint"](ts, y[s], 1.0, max_run=256)
-        recs = PROTOCOLS["singlestream"](out, ts, y[s])
-        expect = sum(r.nbytes for r in recs)
-        assert int(nbytes[s]) == int(expect), s
 
 
 # ---------------------------------------------------------------------------

@@ -287,7 +287,10 @@ def _host(events):
 @pytest.mark.parametrize("method,protocol", [
     ("linear", "singlestream"), ("swing", "twostreams"),
     ("angle", "singlestreamv"), ("disjoint", "implicit"),
-    ("swing", "implicit")])
+    ("swing", "implicit"), ("angle", "twostreams"),
+    ("angle", "singlestream"), ("disjoint", "twostreams"),
+    ("disjoint", "singlestream"), ("disjoint", "singlestreamv"),
+    ("linear", "twostreams"), ("linear", "singlestreamv")])
 def test_slot_plane_emitter_matches_one_emitter_per_row(method, protocol):
     """Per row and per tick, a shard's one ``SlotPlaneEmitter`` gives the
     bytes (twostreams pairs included) that one ``ProtocolEmitter`` per row

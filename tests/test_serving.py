@@ -36,8 +36,11 @@ try:
 except ImportError:
     HAVE_HYPOTHESIS = False
 
-from repro.core.evaluate import BATCHED_SEGMENTERS, METHOD_KNOT_KINDS
+from repro.core.evaluate import (BATCHED_SEGMENTERS, COMBINATIONS,
+                                 METHOD_KNOT_KINDS)
+from repro.core.jax_pla import DEFERRED_METHODS
 from repro.core.protocol_engine import encode_batch
+from repro.core.protocols import PROTOCOL_CAPS
 from repro.serving import (FleetFull, GlobalEpsBudget, INACTIVE_EPS,
                            ServeLoop, SlotManager)
 
@@ -63,12 +66,40 @@ def _offline_bytes(y, method="linear", protocol="singlestream",
     return b"".join(rec) if isinstance(rec, tuple) else rec
 
 
+def _store_for(protocol):
+    """An archive for twostreams, whose wire interleaves the pair tick by
+    tick; the archive keeps the two streams apart, as the offline encoder
+    does."""
+    from repro.store import SegmentStore
+    return SegmentStore(protocol, eps=EPS) \
+        if protocol == "twostreams" else None
+
+
+def _assert_offline(wire, y, method, protocol, max_run, store=None,
+                    key=None):
+    """A stream's lifetime wire equals the offline encode of its data;
+    for twostreams the archived pair equals the offline pair and the
+    interleaved wire has its length."""
+    ref = _offline_parts(y, method, protocol, max_run=max_run)
+    if protocol == "twostreams":
+        held = store._streams[key]
+        assert (bytes(held.payload), bytes(held.payload2)) == ref, key
+        assert len(wire) == len(b"".join(ref)), key
+    else:
+        assert wire == ref, key
+
+
 # ---------------------------------------------------------------------------
 # Slot recycling: generation N output == fresh run, bytes == offline
 # ---------------------------------------------------------------------------
 
 CHURN_COMBOS = [("linear", "singlestream"), ("swing", "twostreams"),
                 ("angle", "singlestreamv"), ("disjoint", "implicit")]
+
+# The 11 Table-2 combinations serving admits: all but the deferred
+# methods (C, M), which the slot plane rejects.
+SERVE_COMBOS = [COMBINATIONS[k] for k in sorted(COMBINATIONS)
+                if COMBINATIONS[k][0] not in DEFERRED_METHODS]
 
 
 def _churn_body(seed, n_ops, method="linear", protocol="singlestream",
@@ -83,10 +114,8 @@ def _churn_body(seed, n_ops, method="linear", protocol="singlestream",
     their segments reach ``max_run``; offers outpace the ticks, so evicts
     drain queued points; and every draw ends by evicting a stream and
     admitting another into its slot."""
-    from repro.store import SegmentStore
     rng = np.random.default_rng(seed)
-    store = SegmentStore(protocol, eps=EPS) \
-        if protocol == "twostreams" else None
+    store = _store_for(protocol)
     mgr = SlotManager(method, protocol, capacity=capacity, eps0=EPS,
                       max_run=64, store=store)
     loop = ServeLoop(mgr, tick_width=16, queue_cap=1 << 16)
@@ -125,17 +154,8 @@ def _churn_body(seed, n_ops, method="linear", protocol="singlestream",
         wire[sid] += rep.tail
         y = np.concatenate(fed[sid])
         if y.size:
-            ref = _offline_parts(y, method, protocol, max_run=64)
-            if protocol == "twostreams":
-                # the wire interleaves the two streams tick by tick; the
-                # archive keeps them apart, as the offline encoder does
-                held = store._streams[(sid, rep.slot, rep.generation)]
-                assert (bytes(held.payload), bytes(held.payload2)) == ref, \
-                    (sid, rep.slot, rep.generation)
-                ref = b"".join(ref)
-            assert wire[sid] == ref if protocol != "twostreams" \
-                else len(wire[sid]) == len(ref), \
-                (sid, rep.slot, rep.generation)
+            _assert_offline(wire[sid], y, method, protocol, 64, store,
+                            (sid, rep.slot, rep.generation))
             assert rep.points == y.size
         assert rep.nbytes == len(wire[sid])
 
@@ -173,7 +193,8 @@ def test_churn_fixed_draws():
         _churn_body(seed, 40)
 
 
-@pytest.mark.parametrize("method,protocol", CHURN_COMBOS)
+@pytest.mark.parametrize("method,protocol",
+                         SERVE_COMBOS + [("swing", "twostreams")])
 def test_churn_other_combinations(method, protocol):
     _churn_body(3, 30, method=method, protocol=protocol)
 
@@ -185,13 +206,17 @@ if HAVE_HYPOTHESIS:
         _churn_body(seed, n_ops)
 
 
-def test_recycled_slot_bit_identical_to_fresh_run():
+@pytest.mark.parametrize("method,protocol", SERVE_COMBOS)
+def test_recycled_slot_bit_identical_to_fresh_run(method, protocol):
     """Generation 2 of a slot == the same data through a generation-1
     manager: the slot plane carries no memory of its previous occupant."""
     rng = np.random.default_rng(5)
     ya, yb = _walk(rng, 300), _walk(rng, 300)
+    max_run = PROTOCOL_CAPS[protocol] or 256
+    kw = dict(capacity=1, eps0=EPS, max_run=max_run)
 
-    mgr = SlotManager("linear", capacity=1, eps0=EPS)
+    store = _store_for(protocol)
+    mgr = SlotManager(method, protocol, store=store, **kw)
     mgr.admit("a")
     lengths = np.full(1, 100, np.int64)
     for k in range(3):
@@ -205,14 +230,16 @@ def test_recycled_slot_bit_identical_to_fresh_run():
             blobs += b
     blobs += mgr.evict("b").tail
 
-    fresh = SlotManager("linear", capacity=1, eps0=EPS)
+    fresh = SlotManager(method, protocol, **kw)
     fresh.admit("b")
     ref = b""
     for k in range(3):
         for _, _, b in fresh.step(yb[None, 100 * k:100 * (k + 1)], lengths):
             ref += b
     ref += fresh.evict("b").tail
-    assert blobs == ref == _offline_bytes(yb)
+    assert blobs == ref
+    _assert_offline(blobs, yb, method, protocol, max_run, store,
+                    ("b", slot.index, 2))
 
 
 # ---------------------------------------------------------------------------
@@ -256,12 +283,16 @@ def test_deferred_methods_rejected():
 # Tick loop: phasing invariance + backpressure
 # ---------------------------------------------------------------------------
 
-def test_tick_phasing_leaves_no_trace_in_wire():
+@pytest.mark.parametrize("method,protocol", SERVE_COMBOS)
+def test_tick_phasing_leaves_no_trace_in_wire(method, protocol):
     """Out-of-phase ragged offers produce the same per-stream bytes as
     the offline encode — tick batching is pure transport."""
     rng = np.random.default_rng(9)
     data = {f"s{i}": _walk(rng, 257 + 31 * i) for i in range(3)}
-    loop = ServeLoop(SlotManager("linear", capacity=4, eps0=EPS),
+    max_run = PROTOCOL_CAPS[protocol] or 256
+    store = _store_for(protocol)
+    loop = ServeLoop(SlotManager(method, protocol, capacity=4, eps0=EPS,
+                                 max_run=max_run, store=store),
                      tick_width=48, queue_cap=4096)
     got = {sid: b"" for sid in data}
     cursors = {sid: 0 for sid in data}
@@ -277,8 +308,10 @@ def test_tick_phasing_leaves_no_trace_in_wire():
         for sid, _, blob in rep.wire:
             got[sid] += blob
     for sid, y in data.items():
-        got[sid] += loop.evict(sid).tail
-        assert got[sid] == _offline_bytes(y), sid
+        rep = loop.evict(sid)
+        got[sid] += rep.tail
+        _assert_offline(got[sid], y, method, protocol, max_run, store,
+                        (sid, rep.slot, rep.generation))
 
 
 def test_backpressure_shed_counts_drops():
@@ -483,21 +516,28 @@ def test_serving_churn_8_devices_subprocess(method, protocol):
 
 
 # ---------------------------------------------------------------------------
-# CLI: --smoke is finally disableable; fleet mode parses
+# CLI: the fleet-serving front-end parses and runs
 # ---------------------------------------------------------------------------
-
-def test_serve_cli_smoke_flag_both_ways():
-    from repro.launch.serve import build_parser
-    p = build_parser()
-    assert p.parse_args([]).smoke is True
-    assert p.parse_args(["--smoke"]).smoke is True
-    assert p.parse_args(["--no-smoke"]).smoke is False   # the old bug
-
 
 def test_serve_cli_fleet_args():
     from repro.launch.serve import build_parser
     a = build_parser().parse_args(
-        ["--fleet", "--fleet-streams", "4", "--churn", "0.2",
+        ["--fleet-streams", "4", "--churn", "0.2",
          "--budget-bytes-per-s", "500"])
-    assert a.fleet and a.fleet_streams == 4
+    assert a.fleet_streams == 4 and a.churn == 0.2
     assert a.budget_bytes_per_s == 500.0
+
+
+def test_serve_cli_fleet_main_runs(capsys):
+    """``main`` serves a small churny fleet end to end: a tick line every
+    ten ticks and on the last, then the summary line."""
+    from repro.launch.serve import main
+    main(["--fleet-streams", "4", "--fleet-ticks", "6", "--tick-width", "16",
+          "--churn", "0.25", "--budget-bytes-per-s", "400"])
+    lines = capsys.readouterr().out.splitlines()
+    ticks = [ln for ln in lines if ln.startswith("tick ")]
+    assert len(ticks) == 2, lines
+    assert all("live=4" in ln and "shed=0" in ln for ln in ticks), ticks
+    assert "pool=" in ticks[-1]
+    assert lines[-1].startswith("served 384 points"), lines[-1]
+    assert "across 10 stream admissions" in lines[-1]

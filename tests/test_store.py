@@ -121,7 +121,7 @@ def test_locate_is_monotone_and_bounded():
 # Closed-form analytics vs brute force
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("key", ["A1", "L2", "C3", "Sw", "M"])
+@pytest.mark.parametrize("key", sorted(COMBINATIONS))
 def test_query_bounds_contain_brute_force(key):
     method, protocol = COMBINATIONS[key]
     eps, S, T = 0.5, 3, 900
@@ -182,18 +182,24 @@ def test_query_on_time_grid_with_offset_and_stride():
 # Blob hand-offs: fleet ingest and serving slots feed the same archive
 # ---------------------------------------------------------------------------
 
-@pytest.mark.parametrize("protocol", PROTOCOLS)
-def test_fleet_handoff_equals_offline_store(protocol):
+# All 13 Table-2 combinations, plus linear/implicit (off the table).
+HANDOFF_COMBOS = dict(COMBINATIONS, **{"linear-implicit":
+                                       ("linear", "implicit")})
+
+
+@pytest.mark.parametrize("key", sorted(HANDOFF_COMBOS))
+def test_fleet_handoff_equals_offline_store(key):
     from repro.sharding.fleet import FleetStream
 
+    method, protocol = HANDOFF_COMBOS[key]
     S, T, eps = 4, 500, 0.5
     y = _make(5, S, T)
     store = SegmentStore(protocol, eps=eps)
-    fs = FleetStream("linear", protocol, S, eps, store=store)
+    fs = FleetStream(method, protocol, S, eps, store=store)
     for lo in range(0, T, 77):
         fs.push(y[:, lo:lo + 77])
     fs.finish()
-    off = _build_store("linear", protocol, y, eps)
+    off = _build_store(method, protocol, y, eps)
     assert store.keys() == off.keys()
     for k in store.keys():
         assert store.n_points(k) == off.n_points(k) == T
@@ -207,14 +213,14 @@ def test_fleet_handoff_equals_offline_store(protocol):
         == off.query("avg", list(range(S)), 40.0, 460.0)
 
 
-@pytest.mark.parametrize("protocol", ["singlestream", "twostreams"])
+@pytest.mark.parametrize("protocol", PROTOCOLS)
 def test_slots_handoff_equals_offline_store(protocol):
     from repro.serving.slots import SlotManager
 
     eps = 0.5
     store = SegmentStore(protocol, eps=eps)
     mgr = SlotManager("linear", protocol, capacity=2, eps0=eps,
-                      store=store)
+                      max_run=PROTOCOL_CAPS[protocol] or 256, store=store)
     y = _make(6, 1, 300)[0]
     slot = mgr.admit("s0")
     key = ("s0", slot.index, slot.generation)

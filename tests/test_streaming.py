@@ -16,10 +16,8 @@ import jax.numpy as jnp
 import pytest
 
 from repro.core import jax_pla
-from repro.core.jax_pla import (SegmentOutput, STREAMING_METHODS, flush,
-                                init_state, propagate_lines, records_append,
-                                records_finalize, records_init, step_chunk,
-                                to_records)
+from repro.core.jax_pla import (STREAMING_METHODS, flush,
+                                init_state, propagate_lines, step_chunk)
 from repro.kernels.ops import KERNEL_SEGMENTERS, StreamingSegmenter
 from repro.kernels.reconstruct import reconstruct_pallas
 
@@ -129,61 +127,6 @@ def test_core_flush_restarts_fresh_stream():
     got = np.concatenate([np.asarray(o2.breaks), np.asarray(f2.breaks)],
                          axis=1)
     np.testing.assert_array_equal(got, np.asarray(off2.breaks))
-
-
-def test_records_incremental_equals_batch():
-    y = _make(3, 7, 130)
-    seg = REF_FNS["disjoint"](y, 1.0, max_run=16)
-    for k_max in (4, 16, 64):  # k_max=4 forces overflow rows
-        batch = to_records(seg, k_max)
-        rec = records_init(7, k_max)
-        pos = 0
-        for w in (1, 40, 64, 25):
-            chunk = SegmentOutput(seg.breaks[:, pos:pos + w],
-                                  seg.a[:, pos:pos + w],
-                                  seg.v[:, pos:pos + w])
-            rec = records_append(rec, chunk, pos)
-            pos += w
-        rec = records_finalize(rec, 130)
-        for f in batch._fields:
-            np.testing.assert_array_equal(np.asarray(getattr(rec, f)),
-                                          np.asarray(getattr(batch, f)),
-                                          err_msg=f"k_max={k_max}/{f}")
-        if k_max == 4:
-            assert bool(batch.overflow.any())
-
-
-def test_kv_streaming_blocks_equal_one_shot():
-    from repro.compression.kv_cache import (PLAKVConfig,
-                                            StreamingKVCompressor,
-                                            compress_kv_block,
-                                            decompress_kv_block)
-    rng = np.random.default_rng(4)
-    k = jnp.asarray(np.cumsum(rng.normal(0, 0.05, (2, 512, 2, 8)), 1),
-                    jnp.float32)
-    v = jnp.asarray(np.cumsum(rng.normal(0, 0.05, (2, 512, 2, 8)), 1),
-                    jnp.float32)
-    cfg = PLAKVConfig(eps=0.05, k_max=48)
-    sc = StreamingKVCompressor(cfg)
-    blocks = []
-    pos = 0
-    for w in (1, 37, 100, 150, 120, 104):  # straddles the 256 boundary
-        blocks += sc.push(k[:, pos:pos + w], v[:, pos:pos + w])
-        pos += w
-    assert pos == 512 and len(blocks) == 2 and sc.pending_tokens == 0
-    for b, lo in zip(blocks, (0, 256)):
-        ref = compress_kv_block(k[:, lo:lo + 256], v[:, lo:lo + 256], cfg)
-        for fld in ("k_rec", "v_rec"):
-            for f in ref.k_rec._fields:
-                np.testing.assert_array_equal(
-                    np.asarray(getattr(getattr(b, fld), f)),
-                    np.asarray(getattr(getattr(ref, fld), f)),
-                    err_msg=f"block@{lo}/{fld}/{f}")
-        np.testing.assert_array_equal(np.asarray(b.k_raw),
-                                      np.asarray(ref.k_raw))
-        kd, vd = decompress_kv_block(b, cfg)
-        assert float(jnp.abs(kd - k[:, lo:lo + 256]).max()) <= \
-            cfg.eps + 6e-3 * float(jnp.abs(k).max()) + 1e-4
 
 
 def test_reconstruct_carry_split_equals_one_launch():
